@@ -101,11 +101,10 @@ class SliceExecutor:
                       seed: int = 0):
         """Fresh (lora, opt) state for this pack shape, from a cached
         template: adapter init depends only on (seed, model config, pack
-        meta), and ``init_model`` is expensive enough (~10s on a reduced
-        config: it also materializes a base model we throw away) that
-        rebuilding it per segment dominated segment runtime. Returned trees
-        share leaves with the cache — callers get fresh containers, and
-        placement copies the leaves before anything donates them."""
+        meta), and rebuilding it per segment dominated segment runtime.
+        ``init_lora`` builds the adapters without the base model. Returned
+        trees share leaves with the cache — callers get fresh containers,
+        and placement copies the leaves before anything donates them."""
         meta = pack_meta(configs)
         # adapter init depends only on the rank tuple (shapes + rank mask),
         # not on alphas / learning rates / batch sizes
@@ -113,10 +112,10 @@ class SliceExecutor:
         with self._lock:
             hit = self._templates.get(key)
         if hit is None:
-            from repro.models.model import init_model
+            from repro.models.model import init_lora
             from repro.train.optimizer import init_opt_state
 
-            _, lora = init_model(jax.random.PRNGKey(seed), cfg, meta)
+            lora = init_lora(jax.random.PRNGKey(seed), cfg, meta)
             opt = init_opt_state(lora, n_pack=meta.n)
             hit = (lora, opt)
             with self._lock:

@@ -35,8 +35,8 @@ segment's device units stay within one host; the dispatcher rejects
 host-spanning slices.
 
 This module is import-light on purpose: the spawn'd child imports it before
-any jax backend initializes, and the dispatcher side works without touching
-jax until a segment actually runs.
+any jax backend initializes. The dispatcher checks only that its own backend
+is the CPU (:func:`require_cpu_parent`).
 """
 from __future__ import annotations
 
@@ -789,6 +789,25 @@ class DispatchExecutor:
             ) from last_died
 
 
+def require_cpu_parent() -> None:
+    """Refuse to simulate hosts from a process whose JAX runs on a chip.
+
+    Workers are CPU subprocesses. A chip belongs to one process, so a parent
+    that holds one would leave the workers nothing but the CPU: the run
+    would quietly train there. The chips of one host are units of one
+    in-process :class:`~repro.cluster.pool.DevicePool` instead."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the multi-host tier simulates hosts as CPU subprocesses, but "
+            f"this process runs JAX on {backend!r} and holds its chips; run "
+            "the chips of this host as units of one DevicePool (ClusterRunner"
+            "; launch/train.py without --hosts/--devices-per-host)"
+        )
+
+
 class HostDispatcher:
     """Process-per-host execution of planned segments.
 
@@ -805,7 +824,8 @@ class HostDispatcher:
     ``transport_factory(host_id, n_devices)`` defaults to spawning a real
     subprocess (:class:`ProcessTransport`); tests inject in-memory fakes.
     Workers are started lazily, restarted on death (``max_restarts`` per
-    segment), and torn down by ``close()`` / the context manager."""
+    segment), and torn down by ``close()`` / the context manager. The
+    parent's JAX must run on the CPU (:func:`require_cpu_parent`)."""
 
     def __init__(
         self,
@@ -823,6 +843,7 @@ class HostDispatcher:
         send_deadline: float = 30.0,
         send_retries: int = 2,
     ):
+        require_cpu_parent()
         if isinstance(hosts, int):
             hosts = [devices_per_host] * hosts
         self.hosts: Tuple[int, ...] = tuple(int(n) for n in hosts)

@@ -96,7 +96,10 @@ def init_lora_pair(
     starts at exactly zero (standard LoRA init, paper Fig. 1 convention).
     """
     n, r = meta.n, meta.r_bucket
-    a = jax.random.normal(key, (n, d_in, r), dtype) / jnp.sqrt(d_in).astype(dtype)
+    # the barrier keeps XLA from rewriting the division as a multiply by the
+    # reciprocal under jit, so jitted and eager inits agree bit for bit
+    std = jax.lax.optimization_barrier(jnp.sqrt(d_in).astype(dtype))
+    a = jax.random.normal(key, (n, d_in, r), dtype) / std
     a = a * meta.rank_mask()[:, None, :].astype(dtype)
     b = jnp.zeros((n, r, d_out), dtype)
     return {"a": a, "b": b}

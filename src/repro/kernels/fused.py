@@ -56,6 +56,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.packed_matmul import pallas_interpret
 from repro.kernels.quant import NF4_CODEBOOK, dequantize, is_quantized
 
 # default Pallas tile sizes; the autotuner (kernels/autotune.py) overrides
@@ -80,8 +81,10 @@ def _fused_kernel(
     ``acc`` accumulates the base tile ``x @ W``; ``xa`` accumulates the
     A-contraction off the SAME x tile (rank is never tiled: it fits one lane
     width). On the last K step the delta is applied in-register and the
-    output tile is written exactly once.
+    output tile is written exactly once. ``scale_ref`` is the whole (N,)
+    per-adapter scale vector in SMEM.
     """
+    n = pl.program_id(0)
     k = pl.program_id(3)
 
     @pl.when(k == 0)
@@ -95,7 +98,7 @@ def _fused_kernel(
 
     @pl.when(k == n_k - 1)
     def _store():
-        scale = scale_ref[0, 0]
+        scale = scale_ref[n]
         delta = jnp.dot(
             xa_ref[...],
             b_ref[0].astype(jnp.float32),
@@ -115,8 +118,10 @@ def _dequant_tile(wq, ws, mode, blk, dtype):
     if mode == "int8":
         w = wq.astype(jnp.float32) * ws  # (bk, bl) * (1, bl)
     else:  # nf4: unpack 2 codes per uint8 (low nibble = even K-row)
-        lo = (wq & 0xF).astype(jnp.int32)
-        hi = (wq >> 4).astype(jnp.int32)
+        # widened first: Mosaic cannot lower shifts of 8-bit integers
+        w32 = wq.astype(jnp.int32)
+        lo = w32 & 0xF
+        hi = w32 >> 4
         p, bl = wq.shape
         idx = jnp.stack([lo, hi], axis=1).reshape(2 * p, bl)
         # codebook lookup as a select chain: Pallas kernels cannot capture
@@ -138,6 +143,7 @@ def _fused_kernel_q(
     VMEM scratch; the only change is that the W tile is dequantized
     in-register before the base dot (codes + scales stream in as two
     operands instead of one dense tile)."""
+    n = pl.program_id(0)
     k = pl.program_id(3)
 
     @pl.when(k == 0)
@@ -152,7 +158,7 @@ def _fused_kernel_q(
 
     @pl.when(k == n_k - 1)
     def _store():
-        scale = scale_ref[0, 0]
+        scale = scale_ref[n]
         delta = jnp.dot(
             xa_ref[...],
             b_ref[0].astype(jnp.float32),
@@ -176,20 +182,23 @@ def fused_matmul(
     block_m: int = DEFAULT_BLOCKS[0],
     block_l: int = DEFAULT_BLOCKS[1],
     block_k: int = DEFAULT_BLOCKS[2],
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """out[n] = x[n] @ w + scale[n] * (x[n] @ a[n]) @ b[n].
 
     x: (N, M, K); w: (K, L) shared; a: (N, K, r); b: (N, r, L); scale: (N,).
     Inputs are zero-padded to tile multiples (exact for contractions; the
     output is sliced back); the rank dim is padded to one lane width and
-    never tiled. ``interpret=True`` validates on CPU; on TPU pass False.
+    never tiled. ``interpret=None`` follows ``pallas_interpret()``: compiled
+    on a TPU, interpreted on the CPU.
 
     With ``w_scales``, ``w`` is quantized codes instead of a dense weight —
     int8 codes (K, L) with per-channel scales (1, L), or packed nf4 uint8
     codes (K//2, L) with block scales (K//blk, L) — and the kernel
     dequantizes each W tile in-register inside the K-loop.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
     n, m, k = x.shape
     if w_scales is None:
         mode, blk = None, 0
@@ -206,7 +215,7 @@ def fused_matmul(
     )
     if scale is None:
         scale = jnp.ones((n,), dtype=jnp.float32)
-    scale = scale.astype(jnp.float32).reshape(n, 1)
+    scale = scale.astype(jnp.float32).reshape(n)
 
     # TPU-aligned tiles: last dim multiple of 128 (lanes), 2nd-to-last of 8;
     # the rank lives inside one 128-lane register tile (never grid-tiled).
@@ -245,7 +254,7 @@ def fused_matmul(
     x_spec = pl.BlockSpec((1, bm, bk), lambda ad, i, j, s: (ad, i, s))
     a_spec = pl.BlockSpec((1, bk, rp), lambda ad, i, j, s: (ad, s, 0))
     b_spec = pl.BlockSpec((1, rp, bl), lambda ad, i, j, s: (ad, 0, j))
-    s_spec = pl.BlockSpec((1, 1), lambda ad, i, j, s: (ad, 0))
+    s_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     if mode is None:
         kernel = functools.partial(_fused_kernel, n_k=n_k)
         in_specs = [
@@ -332,7 +341,6 @@ def _run_fwd(x, w, a, b, alpha, impl, blocks):
                 x3, wq, a.astype(x.dtype), b.astype(x.dtype),
                 alpha, ws,
                 block_m=bm, block_l=bl, block_k=bk,
-                interpret=jax.default_backend() != "tpu",
             )
         else:
             d_out = w.shape[-1]
@@ -340,7 +348,6 @@ def _run_fwd(x, w, a, b, alpha, impl, blocks):
                 x3, w.astype(x.dtype), a.astype(x.dtype), b.astype(x.dtype),
                 alpha,
                 block_m=bm, block_l=bl, block_k=bk,
-                interpret=jax.default_backend() != "tpu",
             )
         return out.reshape(x.shape[0], *lead, d_out)
     return _fused_xla(x, w, a.astype(x.dtype), b.astype(x.dtype), alpha)
@@ -397,7 +404,6 @@ def _bwd(impl, remat, blocks, res, g):
             jnp.swapaxes(a_c, 1, 2),
             alpha,
             block_m=bm, block_l=bl, block_k=bk,
-            interpret=jax.default_backend() != "tpu",
         ).reshape(g.shape[0], *lead, wd.shape[0])
     else:
         dx = (

@@ -24,6 +24,9 @@ Backend selection (``KernelConfig.impl`` / the ``impl=`` kwarg):
                         benches measure real XLA wall-clock, TPU gets the
                         custom kernel)
 
+A step sharded over several TPU chips takes the XLA forms instead
+(``sharded_impl``): XLA cannot partition a Mosaic kernel.
+
 The process default is a ``contextvars.ContextVar`` (NOT a mutable global):
 ``set_default_impl`` only affects the calling context, so the thread-per-
 slice ``ClusterRunner`` can never race it. New threads do NOT inherit the
@@ -55,6 +58,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 from repro.kernels.packed_matmul import packed_matmul as _pallas_matmul
+from repro.kernels.packed_matmul import pallas_interpret
 
 IMPLS = ("auto", "pallas", "xla", "fused", "fused_pallas", "fused_xla")
 
@@ -105,6 +109,25 @@ def _resolve(impl: Optional[str]) -> str:
     if impl == "fused":
         return "fused_pallas" if on_tpu else "fused_xla"
     return impl
+
+
+def sharded_impl(impl: Optional[str]) -> Optional[str]:
+    """The impl of a step sharded over several devices.
+
+    XLA cannot partition a compiled Mosaic kernel, and the kernels here are
+    not wrapped in ``shard_map``, so on a TPU ``auto`` and ``fused`` take
+    their XLA forms there and an explicit Pallas impl is refused. Kernels
+    that are interpreted (CPU) lower to plain XLA and stay as asked."""
+    if pallas_interpret():
+        return impl
+    impl = impl or _IMPL_VAR.get()
+    if impl in ("pallas", "fused_pallas"):
+        raise ValueError(
+            f"impl={impl!r} cannot run on a step sharded over several "
+            "devices: XLA cannot partition a Mosaic kernel (use 'auto', "
+            "'fused' or an XLA impl)"
+        )
+    return {"auto": "xla", "fused": "fused_xla"}.get(impl, impl)
 
 
 def _unfused(impl: str) -> str:
@@ -198,9 +221,7 @@ def grouped_matmul(x, w, scale=None, *, impl: Optional[str] = None):
     if _unfused(_resolve(impl)) == "pallas":
         lead = x.shape[1:-1]
         x3 = x.reshape(x.shape[0], -1, x.shape[-1])
-        out = _pallas_matmul(
-            x3, w, scale, interpret=jax.default_backend() != "tpu"
-        )
+        out = _pallas_matmul(x3, w, scale)
         return out.reshape(x.shape[0], *lead, w.shape[-1])
     return _ref.packed_matmul_ref(x, w, scale)
 

@@ -24,8 +24,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def pallas_interpret() -> bool:
+    """Whether the Pallas kernels run interpreted: compiled on a TPU,
+    interpreted on the CPU (the test oracle). Any other backend is refused
+    rather than given a silent slow path."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and interpret on CPU; backend "
+        f"{backend!r} is neither (use impl='xla' or 'fused_xla')"
+    )
+
+
 def _matmul_kernel(x_ref, w_ref, scale_ref, out_ref, acc_ref, *, n_k: int):
-    """One (adapter, m-tile, l-tile, k-step) grid cell."""
+    """One (adapter, m-tile, l-tile, k-step) grid cell. ``scale_ref`` is the
+    whole (N,) per-adapter scale vector in SMEM."""
+    n = pl.program_id(0)
     k = pl.program_id(3)
 
     @pl.when(k == 0)
@@ -38,8 +55,7 @@ def _matmul_kernel(x_ref, w_ref, scale_ref, out_ref, acc_ref, *, n_k: int):
 
     @pl.when(k == n_k - 1)
     def _store():
-        scale = scale_ref[0, 0]
-        out_ref[0, ...] = (acc_ref[...] * scale).astype(out_ref.dtype)
+        out_ref[0, ...] = (acc_ref[...] * scale_ref[n]).astype(out_ref.dtype)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -58,20 +74,22 @@ def packed_matmul(
     block_m: int = 256,
     block_l: int = 256,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """out[n] = scale[n] * (x[n] @ w[n]).
 
     x: (N, M, K); w: (N, K, L); scale: (N,) or None. Inputs are zero-padded to
     tile multiples (exact for the contraction; output is sliced back), so any
-    shape is accepted. ``interpret=True`` validates on CPU; on TPU pass False.
+    shape is accepted. ``interpret=None`` follows :func:`pallas_interpret`.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
     n, m, k = x.shape
     n2, k2, l = w.shape
     assert n == n2 and k == k2, (x.shape, w.shape)
     if scale is None:
         scale = jnp.ones((n,), dtype=jnp.float32)
-    scale = scale.astype(jnp.float32).reshape(n, 1)
+    scale = scale.astype(jnp.float32).reshape(n)
 
     # TPU-aligned tiles: last dim multiple of 128 (lanes), 2nd-to-last of 8.
     bm = min(block_m, _round_up(m, 8))
@@ -92,7 +110,7 @@ def packed_matmul(
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda a, i, j, s: (a, i, s)),
             pl.BlockSpec((1, bk, bl), lambda a, i, j, s: (a, s, j)),
-            pl.BlockSpec((1, 1), lambda a, i, j, s: (a, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, bm, bl), lambda a, i, j, s: (a, i, j)),
         out_shape=jax.ShapeDtypeStruct((n, mp, lp), x.dtype),

@@ -25,18 +25,26 @@ def _require_devices(n_req: int, shape, axes) -> None:
         )
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the sharding rules here are
+    constraints the partitioner propagates, not explicit-sharding types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     _require_devices(int(np.prod(shape)), shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly forced) host devices exist —
     used by tests that exercise sharding logic without 512 fake devices."""
     _require_devices(data * model, (data, model), ("data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def slice_mesh(src, g: Optional[int] = None, *, data: int = 1,

@@ -22,10 +22,12 @@ import jax
 import numpy as np
 
 from repro.cluster import DevicePool, SliceExecutor
+from repro.cluster.multihost import require_cpu_parent
 from repro.configs.base import LoraConfig, get_config, list_archs, reduced
 from repro.core.adapter import pack_meta
 from repro.core.packed_lora import extract_adapter
 from repro.kernels.quant import quantize_base_params
+from repro.launch.cache import enable_compile_cache
 from repro.models.model import init_model
 from repro.train.checkpoint import CheckpointPool
 
@@ -34,10 +36,18 @@ def _estimator(args, cfg):
     """Profiled estimator shared by the single- and multi-host paths:
     analytic prior for the selected hardware + (optionally pre-seeded)
     observation store."""
-    from repro.sched.cost_model import A10_24G, A100_40G, TPU_V5E, CostModel
+    from repro.sched.cost_model import (
+        A10_24G, A100_40G, TPU_V5E, CostModel, tpu_prior,
+    )
     from repro.sched.profile import ObservationStore, ProfiledCostModel
 
-    hw = {"a100-40g": A100_40G, "a10-24g": A10_24G, "tpu-v5e": TPU_V5E}[args.hw]
+    if args.hw is not None:
+        hw = {"a100-40g": A100_40G, "a10-24g": A10_24G,
+              "tpu-v5e": TPU_V5E}[args.hw]
+    elif jax.default_backend() == "tpu":
+        hw = tpu_prior(jax.devices()[0].device_kind)
+    else:
+        hw = A100_40G
     store = (
         ObservationStore.load(args.profile_in) if args.profile_in
         else ObservationStore()
@@ -280,9 +290,11 @@ def main():
                     help="dump the observation store (with this run's "
                          "measured step time folded in) for reuse via "
                          "--profile-in / the adaptive engine")
-    ap.add_argument("--hw", default="a100-40g",
+    ap.add_argument("--hw", default=None,
                     choices=["a100-40g", "a10-24g", "tpu-v5e"],
-                    help="hardware prior for the plan-vs-measured summary")
+                    help="hardware prior for the plan-vs-measured summary "
+                         "(default: the TPU's own prior from its device "
+                         "kind, a100-40g off-TPU)")
     ap.add_argument("--save-state", action="store_true",
                     help="checkpoint the full packed state (adapters + "
                          "optimizer + step counts) into --pool at the end")
@@ -301,6 +313,7 @@ def main():
                          "histogram summaries) as JSON")
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args()
+    enable_compile_cache()
     if (args.save_state or args.resume_state) and not args.pool:
         ap.error("--save-state/--resume-state require --pool")
     if args.resume_state and args.mesh:
@@ -332,6 +345,10 @@ def main():
 
     tracer = _make_tracer(args)
     if args.hosts > 1 or args.devices_per_host > 1:
+        try:
+            require_cpu_parent()
+        except RuntimeError as e:
+            ap.error(str(e))
         if (args.mesh or args.fsdp or args.seq_parallel or args.save_state
                 or args.resume_state):
             ap.error("--hosts is incompatible with --mesh/--fsdp/"

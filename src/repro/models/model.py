@@ -3,6 +3,7 @@
 Public API (all functional, params are plain pytrees):
 
   init_model(key, cfg, meta, dtype)          -> (base_params, lora_params)
+  init_lora(key, cfg, meta, dtype)           -> lora_params (no base built)
   forward(base, lora, scales, batch, cfg, .) -> (hidden (NB,S,d), aux)
   logits(base, hidden, cfg)                  -> (NB,S,V)   [small seqs only]
   init_caches(cfg, nb, smax)                 -> cache pytree
@@ -61,6 +62,14 @@ def init_model(key, cfg: ModelConfig, meta: Optional[PackMeta], dtype=jnp.float3
     if cfg.n_patch_tokens:
         base["patch_proj"] = init_linear(ks[4], cfg.d_model, cfg.d_model, True, dtype)
     return base, lora
+
+
+def init_lora(key, cfg: ModelConfig, meta: PackMeta, dtype=jnp.float32):
+    """The adapter tree of ``init_model``, bit for bit, without building the
+    base: the init runs under ``jit`` with the base output discarded, so XLA
+    drops its computation. At published widths that base is gigabytes of
+    temporaries."""
+    return jax.jit(lambda k: init_model(k, cfg, meta, dtype)[1])(key)
 
 
 def _embed(base, tokens, cfg, batch):

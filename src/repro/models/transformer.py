@@ -39,23 +39,6 @@ from repro.models.layers.ssm import (
 )
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` appeared (with ``check_vma``) after 0.4.x; older
-    releases ship ``jax.experimental.shard_map`` whose equivalent knob is
-    ``check_rep``. One entry point so the EP path runs on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 @dataclass(frozen=True)
 class LayerSpec:
     mixer: str  # "attn" | "ssm"
@@ -303,7 +286,7 @@ def apply_layer(
                     aux_local = jax.lax.pmean(aux_local, da)
                 return y, aux_local
 
-            y, aux_l = shard_map_compat(
+            y, aux_l = jax.shard_map(
                 _moe_body,
                 mesh=dist.mesh,
                 in_specs=(moe_specs, x_spec),

@@ -188,6 +188,21 @@ TPU_V5E = HardwareSpec("tpu-v5e", 16e9, 197e12, 819e9, 50e9, 256,
                        sat_tokens=1_500.0, layer_overhead=0.2e-3,
                        seq_adapter_overhead=0.01)
 
+# the prior of each TPU, keyed by ``jax.Device.device_kind``
+TPU_PRIORS = {"TPU v5 lite": TPU_V5E}
+
+
+def tpu_prior(device_kind: str) -> HardwareSpec:
+    """The prior for a TPU of this ``device_kind``; a TPU without one is an
+    error, never some other chip's preset."""
+    try:
+        return TPU_PRIORS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no cost-model prior for TPU kind {device_kind!r} (known: "
+            f"{sorted(TPU_PRIORS)})"
+        ) from None
+
 
 def model_param_count(cfg: ModelConfig) -> float:
     """Total parameters (embeddings + stack), honest per-family accounting."""

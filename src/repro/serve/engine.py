@@ -62,7 +62,7 @@ import numpy as np
 from repro.configs.base import LoraConfig, ModelConfig
 from repro.core.adapter import PackMeta, pack_meta
 from repro.core.packed_lora import extract_adapter, inject_adapter
-from repro.models.model import decode_step, init_model, prefill, prefill_chunk
+from repro.models.model import decode_step, init_lora, prefill, prefill_chunk
 from repro.obs import NULL_TRACER, Histogram
 from repro.serve.decode import align_prefill_chunk, pad_caches
 
@@ -587,12 +587,12 @@ class ServeEngine:
         )
         self.base = base_params
         key = jax.random.PRNGKey(seed)
-        _, lora = init_model(key, cfg, self.meta)
+        lora = init_lora(key, cfg, self.meta)
         # device-resident R-row pack + width-1 host template (B = 0: empty
         # rows contribute exactly zero delta even before their scale is
         # zeroed). Admission writes one pack row device-side.
         self._lora = lora
-        _, lora1 = init_model(key, cfg, self.meta1)
+        lora1 = init_lora(key, cfg, self.meta1)
         self._lora1_host = jax.tree.map(np.asarray, lora1)
         # one jitted row write per tree structure (caches / lora), width-R
         # argument donated: admission mutates device state in place

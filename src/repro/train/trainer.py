@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.adapter import PackMeta
-from repro.kernels.ops import KernelConfig
+from repro.kernels.ops import KernelConfig, sharded_impl
 from repro.models.model import forward, unembed_w
 from repro.models.transformer import DistContext
 from repro.train.losses import chunked_cross_entropy
@@ -107,12 +107,15 @@ def make_packed_step(
     bucket-padding FLOPs). ``base_dtype`` marks a quantized frozen base
     ("int8"/"nf4", kernels/quant.py) — the base argument then carries
     {"codes","scales"} dicts in its "w" slots. All are part of the
-    executor's cache key.
+    executor's cache key. On a slice of several chips the Pallas impls
+    take their XLA forms (``kernels.ops.sharded_impl``).
     """
     # homogeneous rank tuples normalize to None: they trace identically
     # (ragged segmentation only engages on mixed ranks), so same-width packs
     # of different uniform ranks keep sharing one executor cache entry
     ranks = tuple(ranks) if ranks and len(set(ranks)) > 1 else None
+    if dist is not None and dist.mesh.size > 1:
+        impl = sharded_impl(impl)
     kcfg = KernelConfig(
         impl=impl, remat=remat, ranks=ranks,
         blocks=tuple(blocks) if blocks is not None else None,
@@ -152,6 +155,8 @@ def make_train_step(
     budgets = (
         jnp.asarray(step_budgets, jnp.int32) if step_budgets is not None else None
     )
+    if dist is not None and dist.mesh.size > 1:
+        impl = sharded_impl(impl)
     kcfg = meta.kernel_config(impl=impl, remat=remat, base_dtype=base_dtype)
 
     def train_step(base, lora, opt_state, batch):

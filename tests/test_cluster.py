@@ -238,6 +238,26 @@ def test_executor_cache_hits_same_shape_packs():
     assert ex.n_builds == 2
 
 
+@pytest.mark.parametrize("arch", ["qwen25-7b", "minicpm3-4b", "jamba-v0.1-52b"])
+def test_pack_template_matches_init_model(arch):
+    """The template is built without the base model, yet its adapters and
+    fresh optimizer state equal, bit for bit, the adapter tree of a full
+    ``init_model`` with the same seed."""
+    from repro.train.optimizer import init_opt_state
+
+    cfg = reduced(get_config(arch))
+    configs = [LoraConfig(rank=r) for r in (8, 16, 32)]
+    meta = pack_meta(configs)
+    _, ref = init_model(jax.random.PRNGKey(5), cfg, meta)
+    lora, opt = SliceExecutor().pack_template(cfg, configs, seed=5)
+    ref_opt = init_opt_state(ref, n_pack=meta.n)
+    for want, got in ((ref, lora), (ref_opt, opt)):
+        assert jax.tree.structure(want) == jax.tree.structure(got)
+        for x, y in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 def test_executor_cache_integration_run_segments():
     """Running two same-shape packs through the engine builds one step and
     one pack template; a third, different-shape pack adds one more."""

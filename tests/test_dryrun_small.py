@@ -231,7 +231,6 @@ def test_moe_ep_shard_map_on_mesh():
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import MoEConfig
         from repro.models.layers.moe import apply_moe, init_moe
-        from repro.models.transformer import shard_map_compat
         from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(2, 4)
@@ -248,7 +247,7 @@ def test_moe_ep_shard_map_on_mesh():
         specs = {"router": {"w": P()}, "w_gate": P("model", None, None),
                  "w_up": P("model", None, None), "w_down": P("model", None, None)}
         with mesh:
-            y, aux = jax.jit(shard_map_compat(
+            y, aux = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(specs, P("data", None, None)),
                 out_specs=(P("data", None, None), P()),

@@ -256,3 +256,31 @@ def test_grouped_matmul_dispatch():
         np.asarray(grouped_matmul(x, w, impl="pallas")),
         rtol=1e-5, atol=1e-5,
     )
+
+
+def test_sharded_impl_takes_xla_forms_where_kernels_compile(monkeypatch):
+    """On a TPU a sharded step cannot hold a Mosaic kernel: auto/fused take
+    their XLA forms and an explicit Pallas impl is refused. Interpreted
+    kernels (CPU) stay as asked."""
+    from repro.kernels import ops
+
+    assert ops.sharded_impl("pallas") == "pallas"
+    monkeypatch.setattr(ops, "pallas_interpret", lambda: False)
+    assert ops.sharded_impl("auto") == "xla"
+    assert ops.sharded_impl("fused") == "fused_xla"
+    assert ops.sharded_impl("fused_xla") == "fused_xla"
+    assert ops.sharded_impl(None) == "xla"  # the context default, "auto"
+    for impl in ("pallas", "fused_pallas"):
+        with pytest.raises(ValueError, match="Mosaic"):
+            ops.sharded_impl(impl)
+
+
+def test_pallas_interpret_follows_backend(monkeypatch):
+    from repro.kernels import packed_matmul as pm
+
+    assert pm.pallas_interpret() is True  # the tests run on the CPU
+    monkeypatch.setattr(pm.jax, "default_backend", lambda: "tpu")
+    assert pm.pallas_interpret() is False
+    monkeypatch.setattr(pm.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        pm.pallas_interpret()

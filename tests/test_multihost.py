@@ -163,6 +163,18 @@ def test_dispatch_across_hosts_translates_units_and_applies_writes():
     assert sorted(pool.adapters) == [f"adapter_{i:04d}" for i in range(4)]
 
 
+def test_dispatcher_refuses_a_parent_that_holds_a_chip(monkeypatch):
+    """Workers are CPU subprocesses: a parent whose JAX runs on a chip would
+    leave them only the CPU, so the dispatcher refuses to start."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    made = []
+    with pytest.raises(RuntimeError, match="CPU subprocesses"):
+        HostDispatcher([1], transport_factory=_fake_factory(made))
+    assert made == []
+
+
 def test_dispatch_resume_ships_state_over_the_wire():
     made = []
     cfgs = {0: _cfg()}

@@ -14,6 +14,7 @@ from repro.sched.cost_model import (
     active_param_count,
     lora_param_count,
     model_param_count,
+    tpu_prior,
 )
 from repro.sched.dtm import dtm
 from repro.sched.knapsack import brute_force, solve_pack
@@ -239,3 +240,11 @@ def test_calibration_scales_time(cm):
     cm2.calibrate(measured_iter_time=2 * t_pred, configs=[c], d=1, seq=SEQ)
     t_new = cm2.iter_time([c], 1, SEQ)
     np.testing.assert_allclose(t_new, 2 * t_pred, rtol=1e-6)
+
+
+def test_tpu_prior_by_device_kind():
+    """A v5e gets its own prior; a TPU without one is an error, never
+    another chip's preset."""
+    assert tpu_prior("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="TPU v4"):
+        tpu_prior("TPU v4")
