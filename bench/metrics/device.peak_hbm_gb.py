@@ -1,0 +1,7 @@
+"""Peak device memory in use, ``memory_stats()["peak_bytes_in_use"]`` after
+the window, on the fullest chip, in GB (1e9 bytes). Layer: device."""
+UNIT = "GB"
+
+
+def read(ctx):
+    return ctx.memory_peak_bytes / 1e9 if ctx.memory_peak_bytes else None
