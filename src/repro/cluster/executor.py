@@ -277,6 +277,7 @@ class SliceExecutor:
         from repro.train.optimizer import init_opt_state
 
         meta = pack_meta(configs)
+        track = _slice_track(slice_)
         if lora is None:
             lora, tmpl_opt = self.pack_template(cfg, configs, seed)
             if opt is None:
@@ -292,15 +293,16 @@ class SliceExecutor:
             impl=impl, remat=remat, ranks=meta.ranks, blocks=blocks,
             base_dtype=base_dtype,
         )
-        vecs = (
-            meta.scales(),
-            meta.lr_vector(),
-            jnp.asarray(budgets, jnp.int32),
-        )
-        real_start = time.perf_counter()
-        base_d, lora_d, opt_d, (scales, lr_vec, budg), put_batch = self._place(
-            slice_, cfg, dist, base, lora, opt, vecs
-        )
+        with self.tracer.span("executor.place", cat="executor", track=track):
+            vecs = (
+                meta.scales(),
+                meta.lr_vector(),
+                jnp.asarray(budgets, jnp.int32),
+            )
+            real_start = time.perf_counter()
+            base_d, lora_d, opt_d, (scales, lr_vec, budg), put_batch = (
+                self._place(slice_, cfg, dist, base, lora, opt, vecs)
+            )
         wall = 0.0
         losses = None
         m = None
@@ -310,31 +312,32 @@ class SliceExecutor:
                 if data_start_steps is not None and any(data_start_steps)
                 else None
             )
-            if data_iter_fn:
-                # custom iterators own their stream; the offsets are passed
-                # through only when a resumed segment actually needs them
-                # AND the callable opts in by accepting ``start_steps`` —
-                # legacy 3-arg iterators keep their pre-offset behavior
-                # (resumed adapters replay the stream) instead of crashing
-                if skip and _accepts_start_steps(data_iter_fn):
-                    it = data_iter_fn(
-                        cfg, list(configs), seq, start_steps=skip
-                    )
+            n_first = min(n_steps, PREGEN_CHUNK)
+            with self.tracer.span("executor.batches", cat="executor",
+                                  track=track, n_steps=n_first):
+                if data_iter_fn:
+                    # custom iterators own their stream; the offsets are
+                    # passed through only when a resumed segment actually
+                    # needs them AND the callable opts in by accepting
+                    # ``start_steps`` — legacy 3-arg iterators keep their
+                    # pre-offset behavior (resumed adapters replay the
+                    # stream) instead of crashing
+                    if skip and _accepts_start_steps(data_iter_fn):
+                        it = data_iter_fn(
+                            cfg, list(configs), seq, start_steps=skip
+                        )
+                    else:
+                        it = data_iter_fn(cfg, list(configs), seq)
                 else:
-                    it = data_iter_fn(cfg, list(configs), seq)
-            else:
-                it = packed_batch_iterator(
-                    cfg, list(configs), seq=seq, start_steps=skip
-                )
-            # Pre-generate + pre-place batches in bounded chunks: the
-            # GIL-bound data synthesis stays out of the (possibly
-            # concurrent) step stream for a whole chunk at a time, while
-            # resident batch memory stays O(PREGEN_CHUNK) instead of
-            # O(n_steps) for long launcher runs.
-            first = [
-                put_batch(next(it))
-                for _ in range(min(n_steps, PREGEN_CHUNK))
-            ]
+                    it = packed_batch_iterator(
+                        cfg, list(configs), seq=seq, start_steps=skip
+                    )
+                # Pre-generate + pre-place batches in bounded chunks: the
+                # GIL-bound data synthesis stays out of the (possibly
+                # concurrent) step stream for a whole chunk at a time,
+                # while resident batch memory stays O(PREGEN_CHUNK)
+                # instead of O(n_steps) for long launcher runs.
+                first = [put_batch(next(it)) for _ in range(n_first)]
             # compile outside the timed region on throwaway copies (the
             # paper times steady state); `x + 0` keeps each copy on the
             # slice's own devices, so donation cannot invalidate the
@@ -354,7 +357,6 @@ class SliceExecutor:
             )
             with self._lock:
                 need_warm = wkey not in self._warmed
-            track = _slice_track(slice_)
             if need_warm:
                 with self.tracer.span(
                     "executor.compile", cat="executor", track=track,
@@ -376,20 +378,35 @@ class SliceExecutor:
                 i = 0
                 batches = first
                 while batches:
-                    for batch in batches:
-                        lora_d, opt_d, m = step(
-                            base_d, lora_d, opt_d, batch, scales, lr_vec, budg
-                        )
-                        if step_callback is not None:
-                            step_callback(i, m)
-                        i += 1
-                    batches = [
-                        put_batch(next(it))
-                        for _ in range(min(n_steps - i, PREGEN_CHUNK))
-                    ]
-                jax.block_until_ready(m["loss"])
+                    with self.tracer.span(
+                        "executor.dispatch", cat="executor", track=track,
+                        first_step=i, n_steps=len(batches),
+                    ):
+                        for batch in batches:
+                            lora_d, opt_d, m = step(
+                                base_d, lora_d, opt_d, batch, scales, lr_vec,
+                                budg,
+                            )
+                            if step_callback is not None:
+                                step_callback(i, m)
+                            i += 1
+                    n_next = min(n_steps - i, PREGEN_CHUNK)
+                    batches = []
+                    if n_next:
+                        with self.tracer.span(
+                            "executor.batches", cat="executor", track=track,
+                            n_steps=n_next,
+                        ):
+                            batches = [
+                                put_batch(next(it)) for _ in range(n_next)
+                            ]
+                with self.tracer.span("executor.wait", cat="executor",
+                                      track=track):
+                    jax.block_until_ready(m["loss"])
                 wall = time.perf_counter() - t0
-            losses = np.asarray(m["per_adapter_loss"])
+            with self.tracer.span("executor.losses", cat="executor",
+                                  track=track):
+                losses = np.asarray(m["per_adapter_loss"])
         return PackResult(
             lora=lora_d,
             opt=opt_d,
@@ -446,7 +463,9 @@ class SliceExecutor:
     ):
         job_cfgs = [configs_by_cid[cid] for cid in seg.config_ids]
         meta = pack_meta(job_cfgs)
-        lora, opt = self.pack_template(cfg, job_cfgs, seed)
+        with self.tracer.span("executor.template", cat="executor",
+                              track=track):
+            lora, opt = self.pack_template(cfg, job_cfgs, seed)
         resumed_ids = [
             cid for cid, st0 in zip(seg.config_ids, seg.start_steps) if st0
         ]
@@ -497,26 +516,27 @@ class SliceExecutor:
         )
         lora, opt, losses = res.lora, res.opt, res.losses
         done = set(seg.done_ids)
-        save_cm = (
-            self.tracer.span(
-                "executor.checkpoint_save", cat="executor", track=track,
-                cids=list(seg.config_ids),
+        with self.tracer.span("executor.save", cat="executor", track=track):
+            save_cm = (
+                self.tracer.span(
+                    "executor.checkpoint_save", cat="executor", track=track,
+                    cids=list(seg.config_ids),
+                )
+                if pool is not None
+                else contextlib.nullcontext()
             )
-            if pool is not None
-            else contextlib.nullcontext()
-        )
-        with save_cm:
-            self._save_segment_state(
-                seg, configs_by_cid, total_steps, meta, pool,
-                lora, opt, losses, done,
+            with save_cm:
+                self._save_segment_state(
+                    seg, configs_by_cid, total_steps, meta, pool,
+                    lora, opt, losses, done,
+                )
+            return JobRecord(
+                ScheduledJob(seg.config_ids, seg.degree, seg.start, seg.end),
+                res.wall_seconds,
+                losses,
+                real_start=res.real_start,
+                real_end=res.real_end,
             )
-        return JobRecord(
-            ScheduledJob(seg.config_ids, seg.degree, seg.start, seg.end),
-            res.wall_seconds,
-            losses,
-            real_start=res.real_start,
-            real_end=res.real_end,
-        )
 
     def _save_segment_state(self, seg, configs_by_cid, total_steps, meta,
                             pool, lora, opt, losses, done):

@@ -256,7 +256,9 @@ class ClusterRunner:
             if self.concurrent
             else None
         )
-        with tracer.span(
+        # the collector's pauses are timed for the whole dispatch loop: a
+        # collection on any thread stops every segment's host work
+        with tracer.watch_gc(), tracer.span(
             "runner.run", cat="runner", n_segments=len(order),
             concurrent=self.concurrent,
         ) as run_span:

@@ -441,24 +441,25 @@ def main():
     pred_profiled = est.iter_time(configs, degree, args.seq)  # before observing
 
     ex = SliceExecutor(tracer=tracer)
-    res = ex.train_pack(
-        cfg,
-        configs,
-        n_steps=args.steps,
-        seq=args.seq,
-        base=base,
-        lora=lora,
-        opt=opt,
-        slice_=slice_,
-        mesh_shape=mesh_shape,
-        fsdp=args.fsdp,
-        seq_parallel=args.seq_parallel,
-        step_callback=log if args.log_every else None,
-        impl=args.impl,
-        remat=args.remat,
-        blocks=blocks,
-        base_dtype=quant,
-    )
+    with tracer.watch_gc():
+        res = ex.train_pack(
+            cfg,
+            configs,
+            n_steps=args.steps,
+            seq=args.seq,
+            base=base,
+            lora=lora,
+            opt=opt,
+            slice_=slice_,
+            mesh_shape=mesh_shape,
+            fsdp=args.fsdp,
+            seq_parallel=args.seq_parallel,
+            step_callback=log if args.log_every else None,
+            impl=args.impl,
+            remat=args.remat,
+            blocks=blocks,
+            base_dtype=quant,
+        )
     device_pool.release(slice_)
     lora, opt = res.lora, res.opt
     print(f"{args.steps} steps in {res.wall_seconds:.1f}s "

@@ -23,12 +23,26 @@ ships finished spans back as plain dicts and re-ingests them with
 :meth:`Tracer.ingest`, which remaps ids, rebases clocks, and reparents
 the worker's root onto the dispatcher-side span.
 
+Profiler: while a tracer is enabled, every ``span()`` also opens a
+``jax.profiler.TraceAnnotation`` of the span's own name on the same thread,
+so a ``jax.profiler`` capture shows the program's spans on the profiler's
+clock beside the device operations. ``jax`` is imported on the first
+enabled span only; without it spans are recorded as before.
+
+Garbage collection: :meth:`Tracer.watch_gc` times Python's collections
+while it is open — every pause into the ``process.gc_pause`` histogram,
+pauses of :data:`GC_SPAN_MIN_S` or more as ``process.gc`` spans under the
+span open on the collecting thread.
+
 Disabled tracing is a true no-op: :data:`NULL_TRACER` returns one shared
-context-manager singleton from ``span()`` and touches no state, so
-always-on call sites cost an attribute lookup and a method call.
+context-manager singleton from ``span()`` and ``watch_gc()``, opens no
+annotation, hooks no collector and touches no state, so always-on call
+sites cost an attribute lookup and a method call.
 """
 from __future__ import annotations
 
+import collections
+import gc
 import json
 import threading
 import time
@@ -48,6 +62,10 @@ TIER_CATS = (
     "serve",
     "autotune",
 )
+
+# collections that pause the process at least this long (seconds) are also
+# recorded as ``process.gc`` spans; shorter ones only feed the histogram
+GC_SPAN_MIN_S = 1e-3
 
 
 @dataclass(frozen=True)
@@ -115,20 +133,45 @@ class _SpanCM:
     """Context manager handed out by :meth:`Tracer.span`.
 
     Not ``@contextmanager``: a plain object with ``__enter__``/``__exit__``
-    is cheaper, and lets the disabled path reuse one shared instance."""
+    is cheaper, and lets the disabled path reuse one shared instance. The
+    profiler annotation opens after the span's start is stamped and closes
+    before its end is, so it nests inside the span."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_ann")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._ann = None
 
     def __enter__(self) -> Span:
         self._tracer._push(self._span)
+        annotation = self._tracer._annotation()
+        if annotation:
+            self._ann = annotation(self._span.name)
+            self._ann.__enter__()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         self._tracer._pop(self._span)
+        return None
+
+
+class _GCWatchCM:
+    """Context manager handed out by :meth:`Tracer.watch_gc`."""
+
+    __slots__ = ("_tracer",)
+
+    def __init__(self, tracer: "Tracer"):
+        self._tracer = tracer
+
+    def __enter__(self) -> None:
+        self._tracer._gc_watch(+1)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._tracer._gc_watch(-1)
         return None
 
 
@@ -177,6 +220,15 @@ class Tracer:
         self._finished: List[Span] = []
         self._next_id = 1
         self._tls = threading.local()
+        # jax.profiler.TraceAnnotation, looked up by the first span (False
+        # where jax is not installed)
+        self._trace_annotation = None
+        # garbage-collector hook state (watch_gc): open watches, the start
+        # of the collection under way, and finished collections not yet
+        # turned into spans — the hook itself takes no lock
+        self._gc_watches = 0
+        self._gc_start = 0.0
+        self._gc_pending: collections.deque = collections.deque()
 
     # -- internal span lifecycle -------------------------------------------
 
@@ -185,6 +237,17 @@ class Tracer:
         if st is None:
             st = self._tls.stack = []
         return st
+
+    def _annotation(self):
+        """``jax.profiler.TraceAnnotation``, imported on first use, so that
+        ``repro.obs`` itself imports without jax; False without jax."""
+        if self._trace_annotation is None:
+            try:
+                from jax.profiler import TraceAnnotation
+            except ImportError:
+                TraceAnnotation = False
+            self._trace_annotation = TraceAnnotation
+        return self._trace_annotation
 
     def _alloc_id(self) -> int:
         with self._lock:
@@ -228,18 +291,7 @@ class Tracer:
             root_id = top.root_id if top else None
         else:
             parent_id = parent
-            root_id = None
-            with self._lock:
-                for s in reversed(self._finished):
-                    if s.span_id == parent:
-                        root_id = s.root_id
-                        break
-            if root_id is None:
-                st = self._stack()
-                for s in reversed(st):
-                    if s.span_id == parent:
-                        root_id = s.root_id
-                        break
+            root_id = self._root_of(parent)
         sid = self._alloc_id()
         sp = Span(name=name, cat=cat, track=track, span_id=sid,
                   parent_id=parent_id,
@@ -271,11 +323,87 @@ class Tracer:
         if not self.enabled:
             return
         sid = self._alloc_id()
+        root_id = self._root_of(parent) if parent is not None else None
         sp = Span(name=name, cat=cat, track=track, span_id=sid,
-                  parent_id=parent, root_id=sid,
+                  parent_id=parent,
+                  root_id=root_id if root_id is not None else sid,
                   start=start, end=end, args=dict(args))
         with self._lock:
             self._finished.append(sp)
+
+    def _root_of(self, span_id: int) -> Optional[int]:
+        """Root id of the finished or open (on this thread) span
+        ``span_id``; None when neither holds it."""
+        with self._lock:
+            for s in reversed(self._finished):
+                if s.span_id == span_id:
+                    return s.root_id
+        for s in reversed(self._stack()):
+            if s.span_id == span_id:
+                return s.root_id
+        return None
+
+    def watch_gc(self):
+        """Time Python's garbage collections while open: ``with
+        tracer.watch_gc(): ...``. One ``gc.callbacks`` hook per tracer,
+        however many watches are open, removed when the last one closes.
+        Every pause goes into the ``process.gc_pause`` histogram (seconds);
+        a pause of :data:`GC_SPAN_MIN_S` or more also becomes a
+        ``process.gc`` span (``cat="process"``, args ``generation`` and
+        ``collected``) under the span open on the collecting thread. A
+        disabled tracer returns the shared no-op context manager."""
+        if not self.enabled:
+            return _NULL_CM
+        return _GCWatchCM(self)
+
+    def _gc_watch(self, delta: int) -> None:
+        with self._lock:
+            self._gc_watches += delta
+            if delta > 0 and self._gc_watches == 1:
+                gc.callbacks.append(self._on_gc)
+            elif delta < 0 and self._gc_watches == 0:
+                gc.callbacks.remove(self._on_gc)
+        self._drain_gc()
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        # runs inside the collector on the collecting thread, which may
+        # hold any lock of this module: record and return, taking none.
+        # Collections never overlap, so one start stamp serves.
+        if phase == "start":
+            self._gc_start = time.perf_counter()
+            return
+        end = time.perf_counter()
+        st = getattr(self._tls, "stack", None)
+        self._gc_pending.append((
+            self._gc_start, end, info["generation"], info["collected"],
+            st[-1] if st else None,
+        ))
+
+    def _drain_gc(self) -> None:
+        """Turn the collections the hook recorded into histogram samples
+        and spans (outside the collector, so locks are safe here)."""
+        pending = self._gc_pending
+        if not pending:
+            return
+        pause = self.metrics.histogram("process.gc_pause")
+        while True:
+            try:
+                start, end, generation, collected, top = pending.popleft()
+            except IndexError:  # emptied, here or by another thread
+                return
+            pause.record(end - start)
+            if end - start < GC_SPAN_MIN_S:
+                continue
+            sid = self._alloc_id()
+            sp = Span(name="process.gc", cat="process",
+                      track=top.track if top else "", span_id=sid,
+                      parent_id=top.span_id if top else None,
+                      root_id=top.root_id if top else sid,
+                      start=start, end=end,
+                      args={"generation": generation,
+                            "collected": collected})
+            with self._lock:
+                self._finished.append(sp)
 
     def current_span_id(self) -> Optional[int]:
         if not self.enabled:
@@ -295,6 +423,7 @@ class Tracer:
         the tree rooted at ``root_id`` — the worker-side flush."""
         if not self.enabled:
             return []
+        self._drain_gc()
         with self._lock:
             mine = [s for s in self._finished if s.root_id == root_id]
             self._finished = [
@@ -333,6 +462,7 @@ class Tracer:
     # -- export ------------------------------------------------------------
 
     def spans(self) -> List[Span]:
+        self._drain_gc()
         with self._lock:
             return list(self._finished)
 
@@ -340,8 +470,7 @@ class Tracer:
         """Build the Chrome trace-event dict: ``X`` events for spans (ts in
         µs relative to tracer start), ``M`` thread-name metadata per track,
         ``C`` counter events from sampled gauges."""
-        with self._lock:
-            finished = list(self._finished)
+        finished = self.spans()
         events: List[Dict[str, Any]] = []
         tids: Dict[str, int] = {}
 
@@ -416,6 +545,7 @@ class Tracer:
 
     def export_metrics(self, path: str) -> None:
         """Write the metrics-registry snapshot JSON to ``path``."""
+        self._drain_gc()
         with open(path, "w") as fh:
             json.dump(self.metrics.to_json(), fh, indent=2)
 

@@ -285,6 +285,77 @@ def test_executor_cache_integration_run_segments():
     assert len(ex._templates) == 2
 
 
+def test_executor_spans_tile_each_segment(tmp_path, monkeypatch):
+    """Each executed segment's spans: template, placement, first batches,
+    the step loop, the losses' copy to the host and the save under
+    ``executor.segment``; the chunked
+    dispatches, the later batches and the final wait under
+    ``executor.train``, one ``executor.train`` per ``runner.segment``."""
+    from repro.cluster import executor as executor_mod
+    from repro.obs import Tracer
+    from repro.train.checkpoint import CheckpointPool
+
+    monkeypatch.setattr(executor_mod, "PREGEN_CHUNK", 2)  # 3 steps: 2 chunks
+    cfg = reduced(get_config("qwen25-7b"))
+    cm = CostModel(cfg, A100_40G)
+    configs = [
+        LoraConfig(rank=8, alpha=8.0, learning_rate=1e-3, batch_size=1, seq_len=16),
+        LoraConfig(rank=16, alpha=16.0, learning_rate=1e-3, batch_size=1, seq_len=16),
+    ]
+    jobs = [ScheduledJob((i,), 1, float(i), float(i + 1)) for i in range(2)]
+    base, _ = init_model(jax.random.PRNGKey(0), cfg, pack_meta(configs))
+    tracer = Tracer()
+    runner = ClusterRunner(SliceExecutor(), DevicePool(jax.devices()[:1]),
+                           concurrent=False, tracer=tracer)
+    ExecutionEngine(cm, 1).run_local(
+        Schedule(jobs, 2.0, 1), configs, cfg, base, n_steps=3, seq=16,
+        runner=runner, pool=CheckpointPool(str(tmp_path / "pool")),
+    )
+    spans = tracer.spans()
+    by_id = {s.span_id: s for s in spans}
+
+    def named(name):
+        return [s for s in spans if s.name == name]
+
+    def children(parent):
+        return sorted((s for s in spans if s.parent_id == parent.span_id),
+                      key=lambda s: s.start)
+
+    segs, runs = named("executor.segment"), named("runner.segment")
+    assert len(segs) == len(runs) == 2
+    for run in runs:
+        inner = [t for t in named("executor.train")
+                 if run.start <= t.start and t.end <= run.end
+                 and t.track == run.track]
+        assert len(inner) == 1
+    for seg in segs:
+        assert by_id[seg.parent_id].name == "runner.segment"
+        kids = [s.name for s in children(seg) if s.name != "executor.compile"]
+        assert kids == ["executor.template", "executor.place",
+                        "executor.batches", "executor.train",
+                        "executor.losses", "executor.save"]
+        for kid in children(seg):
+            assert kid.track == seg.track and kid.cat == "executor"
+            assert seg.start <= kid.start <= kid.end <= seg.end
+        first = next(s for s in children(seg) if s.name == "executor.batches")
+        assert first.args["n_steps"] == 2
+        train = next(s for s in children(seg) if s.name == "executor.train")
+        loop = children(train)
+        assert [s.name for s in loop] == ["executor.dispatch",
+                                          "executor.batches",
+                                          "executor.dispatch",
+                                          "executor.wait"]
+        assert [(s.args["first_step"], s.args["n_steps"]) for s in loop
+                if s.name == "executor.dispatch"] == [(0, 2), (2, 1)]
+        assert loop[1].args["n_steps"] == 1
+        assert all(s.track == seg.track for s in loop)
+        save = next(s for s in children(seg) if s.name == "executor.save")
+        # the pool's own span keeps its place, under the save
+        assert [s.name for s in children(save)] == ["executor.checkpoint_save"]
+    # the runner watched the collector: every collection is in the histogram
+    assert "process.gc_pause" in tracer.metrics.to_json()["histograms"]
+
+
 # ---------------------------------------------------------------------------
 # Mesh helpers
 # ---------------------------------------------------------------------------

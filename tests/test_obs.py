@@ -135,6 +135,183 @@ def test_add_span_and_instant():
     assert mark.parent_id == by_name["outer"].span_id
 
 
+def test_add_span_with_parent_joins_the_parents_tree():
+    tr = Tracer()
+    with tr.span("root", cat="host") as root:
+        with tr.span("child", cat="executor") as child:
+            pass
+    t = time.perf_counter()
+    tr.add_span("late", t, t + 0.1, cat="serve", parent=child.span_id)
+    with tr.span("other", cat="engine"):
+        pass
+    late = next(s for s in tr.spans() if s.name == "late")
+    assert late.parent_id == child.span_id
+    assert late.root_id == root.span_id
+    # the worker-side flush keeps a child recorded after the fact with
+    # its tree, and leaves the unrelated root behind
+    flushed = tr.pop_root(root.span_id)
+    assert {d["name"] for d in flushed} == {"root", "child", "late"}
+    assert [s.name for s in tr.spans()] == ["other"]
+    # an unknown parent leaves the span a root of its own
+    tr.add_span("orphan", t, t + 0.1, parent=10**6)
+    orphan = next(s for s in tr.spans() if s.name == "orphan")
+    assert orphan.root_id == orphan.span_id
+
+
+# ---------------------------------------------------------------------------
+# Profiler annotations
+# ---------------------------------------------------------------------------
+
+
+def _profiled_host_events(tmp_path, body):
+    """{name: [(start_ns, end_ns)]} of the host events a CPU
+    ``jax.profiler`` capture of ``body()`` holds."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.numpy.ones(2).block_until_ready()  # backend up before the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(
+                    (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_enabled_span_writes_nested_profiler_annotations(tmp_path):
+    tr = Tracer()
+
+    def body():
+        with tr.span("executor.train", cat="executor"):
+            with tr.span("executor.dispatch", cat="executor", first_step=0):
+                time.sleep(0.002)
+            with tr.span("executor.wait", cat="executor"):
+                time.sleep(0.002)
+
+    events = _profiled_host_events(tmp_path, body)
+    (train,) = events["executor.train"]
+    (dispatch,) = events["executor.dispatch"]
+    (wait,) = events["executor.wait"]
+    # named as the spans, nested as they were opened
+    assert train[0] <= dispatch[0] < dispatch[1] <= wait[0] < wait[1] \
+        <= train[1]
+    spans = {s.name: s for s in tr.spans()}
+    for name, (a, b) in (("executor.dispatch", dispatch),
+                         ("executor.wait", wait)):
+        assert (b - a) / 1e9 == pytest.approx(
+            spans[name].end - spans[name].start, abs=5e-4)
+
+
+def test_disabled_span_writes_no_profiler_annotation(tmp_path):
+    def body():
+        with NULL_TRACER.span("executor.train", cat="executor"):
+            with NULL_TRACER.span("executor.wait", cat="executor"):
+                time.sleep(0.001)
+
+    events = _profiled_host_events(tmp_path, body)
+    assert "executor.train" not in events and "executor.wait" not in events
+
+
+# ---------------------------------------------------------------------------
+# Garbage-collection pauses
+# ---------------------------------------------------------------------------
+
+
+def _garbage():
+    """Reference cycles only the collector frees."""
+    for _ in range(2000):
+        a = []
+        a.append(a)
+
+
+def test_watch_gc_records_a_collection_under_the_open_span(monkeypatch):
+    import gc
+
+    from repro.obs import trace as trace_mod
+
+    monkeypatch.setattr(trace_mod, "GC_SPAN_MIN_S", 0.0)
+    tr = Tracer()
+    was_enabled = gc.isenabled()
+    gc.disable()  # the cycles wait for the forced collection
+    try:
+        with tr.watch_gc():
+            with tr.span("runner.segment", cat="runner",
+                         track="unit0") as seg:
+                _garbage()
+                gc.collect()
+    finally:
+        if was_enabled:
+            gc.enable()
+    gcs = [s for s in tr.spans() if s.name == "process.gc"]
+    full = [s for s in gcs if s.args["generation"] == 2
+            and s.parent_id == seg.span_id]
+    assert full, gcs
+    sp = full[-1]
+    assert sp.cat == "process" and sp.track == "unit0"
+    assert sp.root_id == seg.root_id
+    assert sp.args["collected"] >= 2000
+    assert seg.start <= sp.start <= sp.end <= seg.end
+    assert tr.metrics.histogram("process.gc_pause").count >= len(gcs)
+
+
+def test_watch_gc_keeps_short_pauses_out_of_the_spans():
+    import gc
+
+    tr = Tracer()
+    with tr.watch_gc():
+        gc.collect(0)  # a young collection: far under a millisecond
+    assert tr.metrics.histogram("process.gc_pause").count >= 1
+    short = [v for v in tr.metrics.histogram("process.gc_pause").values()
+             if v < 1e-3]
+    spans = [s for s in tr.spans() if s.name == "process.gc"]
+    assert len(spans) == tr.metrics.histogram("process.gc_pause").count \
+        - len(short)
+
+
+def test_watch_gc_hooks_once_and_restores_gc_callbacks():
+    import gc
+
+    before = list(gc.callbacks)
+    tr = Tracer()
+    with tr.watch_gc():
+        assert len(gc.callbacks) == len(before) + 1
+        with tr.watch_gc():  # nested watches share one hook
+            assert len(gc.callbacks) == len(before) + 1
+        assert len(gc.callbacks) == len(before) + 1
+    assert gc.callbacks == before
+    with pytest.raises(RuntimeError):
+        with tr.watch_gc():
+            raise RuntimeError("boom")
+    assert gc.callbacks == before
+
+
+def test_watch_gc_on_null_tracer_adds_no_hook():
+    import gc
+
+    before = list(gc.callbacks)
+    cm1 = NULL_TRACER.watch_gc()
+    cm2 = NULL_TRACER.watch_gc()
+    assert cm1 is cm2  # the shared no-op context manager
+    with cm1:
+        assert gc.callbacks == before
+        _garbage()
+        gc.collect()
+    assert gc.callbacks == before
+    assert NULL_TRACER.spans() == []
+    assert NULL_TRACER.metrics.to_json()["histograms"] == {}
+
+
 # ---------------------------------------------------------------------------
 # Metrics registry
 # ---------------------------------------------------------------------------
