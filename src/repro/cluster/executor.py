@@ -37,6 +37,7 @@ from repro.core.adapter import pack_meta
 from repro.core.packed_lora import extract_adapter, inject_adapter
 from repro.cluster.pool import MeshSlice
 from repro.obs import NULL_TRACER
+from repro.train.trainer import make_packed_step, step_rows
 
 # per-adapter step cap meaning "no budget": always larger than any real
 # step count, so the budget mask stays 1.0 and the update is bit-identical
@@ -143,6 +144,7 @@ class SliceExecutor:
         ranks: Optional[Tuple[int, ...]] = None,
         blocks: Optional[Tuple[int, int, int]] = None,
         base_dtype: Optional[str] = None,
+        batch_sizes: Optional[Tuple[int, ...]] = None,
     ) -> Tuple[Callable, Optional[Any]]:
         """Jitted packed step for this (config, pack width, slice shape).
 
@@ -151,14 +153,17 @@ class SliceExecutor:
         hit the same jitted callable (and, through jax's executable cache,
         the same XLA compilation when placed identically). The kernel policy
         (``impl``/``remat``/the pack's static ``ranks`` tuple, which drives
-        ragged same-rank segmentation) is part of the trace, so it is part
-        of the key."""
+        ragged same-rank segmentation) and a mixed ``batch_sizes`` tuple
+        (which picks the rows the step computes) are part of the trace, so
+        they are part of the key."""
         width = 1 if slice_ is None else slice_.width
-        # homogeneous rank tuples normalize to None (trace-identical: ragged
-        # segmentation only engages on mixed ranks) so same-width packs keep
-        # sharing one compiled step across uniform rank buckets
+        # homogeneous rank and batch tuples normalize to None (trace-
+        # identical: ragged segmentation and row slots only engage on mixed
+        # tuples) so same-width packs keep sharing one compiled step
         ranks = tuple(ranks) if ranks and len(set(ranks)) > 1 else None
-        kkey = (impl, remat, ranks, blocks, base_dtype)
+        batch_sizes = (tuple(batch_sizes)
+                       if batch_sizes and len(set(batch_sizes)) > 1 else None)
+        kkey = (impl, remat, ranks, blocks, base_dtype, batch_sizes)
         if width == 1:
             key: Tuple = (cfg, n_pack, 1, kkey)
         else:
@@ -172,8 +177,6 @@ class SliceExecutor:
                 self.n_hits += 1
                 self.tracer.metrics.counter("executor.compile_cache_hits").inc()
                 return hit
-            from repro.train.trainer import make_packed_step
-
             dist = None
             if width > 1:
                 from repro.launch.sharding import make_dist
@@ -186,7 +189,7 @@ class SliceExecutor:
                 )
             step = make_packed_step(
                 cfg, n_pack, dist=dist, impl=impl, remat=remat, ranks=ranks,
-                blocks=blocks, base_dtype=base_dtype,
+                blocks=blocks, base_dtype=base_dtype, batch_sizes=batch_sizes,
             )
             self._steps[key] = (step, dist)
             self.n_builds += 1
@@ -291,8 +294,9 @@ class SliceExecutor:
             cfg, meta.n, slice_, nb=nb, mesh_shape=mesh_shape,
             fsdp=fsdp, seq_parallel=seq_parallel,
             impl=impl, remat=remat, ranks=meta.ranks, blocks=blocks,
-            base_dtype=base_dtype,
+            base_dtype=base_dtype, batch_sizes=meta.batch_sizes,
         )
+        rows = step_rows(meta.batch_sizes, dist)
         with self.tracer.span("executor.place", cat="executor", track=track):
             vecs = (
                 meta.scales(),
@@ -349,7 +353,7 @@ class SliceExecutor:
                 cfg, meta.n, meta.r_bucket, meta.ranks, impl, remat, blocks,
                 base_dtype,
                 None if slice_ is None else slice_.devices,
-                nb, mesh_shape, fsdp, seq_parallel,
+                nb, meta.batch_sizes, mesh_shape, fsdp, seq_parallel,
                 tuple(sorted(
                     (k, tuple(v.shape), str(v.dtype))
                     for k, v in first[0].items()
@@ -373,6 +377,7 @@ class SliceExecutor:
             with self.tracer.span(
                 "executor.train", cat="executor", track=track,
                 n_pack=meta.n, n_steps=n_steps,
+                rows=rows, real_rows=sum(meta.batch_sizes),
             ):
                 t0 = time.perf_counter()
                 i = 0

@@ -11,7 +11,7 @@ property, tested in tests/test_train_packed.py).
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,11 +39,15 @@ def chunked_cross_entropy(
     *,
     chunk: int = 512,
     vocab: int = None,
+    row_owner: Optional[Tuple[int, ...]] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (per_adapter_mean (N,), total scalar = sum of per-adapter means).
 
     hidden: (NB, S, d); labels: (NB, S) with IGNORE for masked positions.
     `vocab`: true vocabulary size when `unembed` is padded.
+    `row_owner`: the adapter of each row, when rows are not N equal groups
+    (a pack's row slots, ``train.trainer.row_slots``); an adapter's mean is
+    still its summed NLL over its own counted tokens.
     """
     nb, s, d = hidden.shape
     mask = (labels != IGNORE).astype(jnp.float32)
@@ -71,8 +75,13 @@ def chunked_cross_entropy(
             (hc, lc, mc),
         )
     # fold (N*B,) -> per-adapter means
-    nll_n = nll.reshape(n_pack, -1).sum(-1)
-    cnt_n = cnt.reshape(n_pack, -1).sum(-1)
+    if row_owner is None:
+        nll_n = nll.reshape(n_pack, -1).sum(-1)
+        cnt_n = cnt.reshape(n_pack, -1).sum(-1)
+    else:
+        owner = jnp.asarray(row_owner, jnp.int32)
+        nll_n = jax.ops.segment_sum(nll, owner, n_pack, indices_are_sorted=True)
+        cnt_n = jax.ops.segment_sum(cnt, owner, n_pack, indices_are_sorted=True)
     per_adapter = nll_n / jnp.maximum(cnt_n, 1.0)
     return per_adapter, per_adapter.sum()
 

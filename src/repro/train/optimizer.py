@@ -28,10 +28,15 @@ def init_opt_state(lora_params, n_pack: int = 0) -> Dict[str, Any]:
     }
 
 
+def pack_axis(path) -> int:
+    """Axis of the pack dim of an adapter leaf: 1 under a 'blocks' stack,
+    else 0."""
+    return 1 if any(getattr(k, "key", None) == "blocks" for k in path) else 0
+
+
 def _lr_shape(path, leaf, n_pack: int):
-    """Axis of the pack dim for this leaf: 1 under a 'blocks' stack, else 0."""
-    in_blocks = any(getattr(k, "key", None) == "blocks" for k in path)
-    ax = 1 if in_blocks else 0
+    """Broadcast shape of a per-adapter vector against this leaf."""
+    ax = pack_axis(path)
     assert leaf.shape[ax] == n_pack, (path, leaf.shape, n_pack)
     shape = [1] * leaf.ndim
     shape[ax] = n_pack

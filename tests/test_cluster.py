@@ -285,6 +285,74 @@ def test_executor_cache_integration_run_segments():
     assert len(ex._templates) == 2
 
 
+def test_executor_keys_steps_by_mixed_batch_tuple():
+    """Packs of one width and rank tuple but different mixed batch tuples
+    run different rows, so they get different steps; uniform batch tuples
+    keep sharing one. Their records have one form, and each
+    ``executor.train`` span says how many rows the step computed."""
+    from repro.obs import Tracer
+
+    cfg = reduced(get_config("qwen25-7b"))
+    ex = SliceExecutor(tracer=Tracer())
+    s12, _ = ex.step_fn(cfg, 2, ranks=(8, 8), batch_sizes=(1, 2))
+    s21, _ = ex.step_fn(cfg, 2, ranks=(8, 8), batch_sizes=(2, 1))
+    s11, _ = ex.step_fn(cfg, 2, ranks=(8, 8), batch_sizes=(1, 1))
+    s22, _ = ex.step_fn(cfg, 2, ranks=(8, 8), batch_sizes=(2, 2))
+    assert s12 is not s21 and s11 is s22 and s12 is not s11
+    packs = {bs: [LoraConfig(rank=8, alpha=8.0, learning_rate=1e-3,
+                             batch_size=b, seq_len=16) for b in bs]
+             for bs in ((1, 2), (2, 1), (2, 2))}
+    base, _ = init_model(jax.random.PRNGKey(0), cfg, pack_meta(packs[(1, 2)]))
+    res = {bs: ex.train_pack(cfg, c, n_steps=2, seq=16, base=base)
+           for bs, c in packs.items()}
+    for r in res.values():
+        assert r.losses.shape == (2,) and np.isfinite(r.losses).all()
+        assert jax.tree.structure(r.lora) == jax.tree.structure(
+            res[(1, 2)].lora)
+        for x, y in zip(jax.tree.leaves((r.lora, r.opt)),
+                        jax.tree.leaves((res[(1, 2)].lora, res[(1, 2)].opt))):
+            assert x.shape == y.shape and x.dtype == y.dtype
+    spans = ex.tracer.spans()
+    trains = [s for s in spans if s.name == "executor.train"]
+    assert [(s.args["rows"], s.args["real_rows"]) for s in trains] == [
+        (3, 3), (3, 3), (4, 4)]
+    # each of the three steps is warmed (compiled) before its own window
+    assert sum(s.name == "executor.compile" for s in spans) == 3
+
+
+def test_grid_plan_compiles_five_step_programs():
+    """The planner packs the ten-point grid (ranks 8-128 x batch 1-2) at
+    qwen2.5-7b widths into five mixed-batch jobs on one chip; run at a small
+    size, those jobs compile five step programs, each computing only the
+    three real rows, and a second pass compiles nothing."""
+    from repro.obs import Tracer
+    from repro.sched.cost_model import tpu_prior
+    from repro.sched.planner import plan
+
+    configs = [LoraConfig(rank=r, alpha=float(r) * a, learning_rate=lr,
+                          batch_size=b, seq_len=16)
+               for r in (8, 16, 32, 64, 128)
+               for b, a, lr in ((1, 1.0, 1e-4), (2, 0.25, 4e-4))]
+    sched = plan(CostModel(get_config("qwen25-7b").replace(n_layers=6),
+                           tpu_prior("TPU v5 lite")), configs, 1, 1024, 4)
+    assert sorted(sorted(configs[i].batch_size for i in j.config_ids)
+                  for j in sched.jobs) == [[1, 2]] * 5
+    cfg = reduced(get_config("qwen25-7b"))
+    tracer = Tracer()
+    runner = ClusterRunner(SliceExecutor(), DevicePool(jax.devices()[:1]),
+                           concurrent=False, tracer=tracer)
+    base, _ = init_model(jax.random.PRNGKey(0), cfg, pack_meta(configs[:1]))
+    eng = ExecutionEngine(CostModel(cfg, A100_40G), 1)
+    for _ in range(2):
+        eng.run_local(sched, configs, cfg, base, n_steps=1, seq=16,
+                      runner=runner)
+    spans = tracer.spans()
+    assert sum(s.name == "executor.compile" for s in spans) == 5
+    trains = [s for s in spans if s.name == "executor.train"]
+    assert len(trains) == 10
+    assert all(s.args["rows"] == s.args["real_rows"] == 3 for s in trains)
+
+
 def test_executor_spans_tile_each_segment(tmp_path, monkeypatch):
     """Each executed segment's spans: template, placement, first batches,
     the step loop, the losses' copy to the host and the save under
