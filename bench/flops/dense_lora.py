@@ -20,7 +20,7 @@ adapters.
 """
 from __future__ import annotations
 
-from bench.weights import lora_projections, projections
+from bench.models.dense_lora import lora_projections, projections
 
 INPUT_PROJS = ("q", "k", "v", "gate", "up")
 ACT, ADAPTER = 2, 4  # bytes per element

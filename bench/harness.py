@@ -3,7 +3,18 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell names a model configuration (``bench/configs/<config>.json``) and a
-traffic mix (``bench/traffic/<mix>.json``). The run:
+traffic mix (``bench/traffic/<mix>.json``). The configuration's ``model``
+names three modules, each ``<model>.py``, and a cell whose model lacks one is
+refused before set-up:
+
+- ``bench/models/``: the model's shape, as ``bench/models/__init__.py`` sets
+  out: ``spec(cfg_file)`` (sizes, with the vocabulary ``"V"``),
+  ``make_weights(seed, spec, device)``, ``program_config(cfg_file)``,
+  ``base_tree(weights, cfg)`` and ``adapter_norms(tree)``;
+- ``bench/reference/``: the plain reference, ``init_adapters`` and ``train``;
+- ``bench/flops/``: the required work of a step, for the per-layer readers.
+
+The run:
 
 1. set-up: makes the frozen base from the seed on the chip, plans the mix's
    grid with the program's planner and the chip's prior, and drives the
@@ -68,8 +79,33 @@ def load_module(path, name=None):
     return mod
 
 
+MODEL_PARTS = ("models", "reference", "flops")
+
+
+def model_files(cfg_file, root=ROOT):
+    """{part: path} of the configuration's model modules,
+    ``bench/<part>/<model>.py``; Refused, naming the path, where one is
+    missing."""
+    model = cfg_file["model"]
+    files = {part: os.path.join(root, "bench", part, f"{model}.py")
+             for part in MODEL_PARTS}
+    for path in files.values():
+        if not os.path.isfile(path):
+            raise Refused(f"configuration {cfg_file['name']!r} names model "
+                          f"{model!r}, and {os.path.relpath(path, root)} is "
+                          f"missing")
+    return files
+
+
+def model_module(cfg_file, part, root=ROOT):
+    """The configuration's model module of ``part`` (``MODEL_PARTS``)."""
+    return load_module(model_files(cfg_file, root)[part],
+                       f"bench_{part}_{cfg_file['model']}")
+
+
 def find_cell(name, root=ROOT):
-    """(benchmark, cell, config file, traffic mix) of the cell ``name``."""
+    """(benchmark, cell, config file, traffic mix) of the cell ``name``;
+    Refused where the configuration's model lacks a module."""
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -79,6 +115,7 @@ def find_cell(name, root=ROOT):
                                       f"{cell['config']}.json"))
     mix = load_json(os.path.join(root, "bench", "traffic",
                                  f"{cell['traffic']}.json"))
+    model_files(cfg_file, root)
     return bench, cell, cfg_file, mix
 
 
@@ -165,14 +202,14 @@ class CompileCounter:
         return sum(1 for t in self.times if a <= t <= b)
 
 
-def setup(cfg_file, mix, *, seed, devices, kind, trace=False):
+def setup(cfg_file, mix, *, seed, devices, kind, trace=False, root=ROOT):
     """Everything before the window: the plan and its sweep, then
     ``reseed``. Returns the run's state."""
     from bench import program
     from bench.traffic.generator import grid_points
-    from bench.weights import model_spec
 
-    cfg = program.program_config(cfg_file)
+    model = model_module(cfg_file, "models", root)
+    cfg = model.program_config(cfg_file)
     points = grid_points(mix)
     seq, k_steps = int(mix["seq"]), int(mix["steps_per_job"])
     tracer = None
@@ -181,11 +218,12 @@ def setup(cfg_file, mix, *, seed, devices, kind, trace=False):
 
         tracer = Tracer()
     st = SimpleNamespace(
-        cfg_file=cfg_file, mix=mix, spec=model_spec(cfg_file), cfg=cfg,
-        points=points, seq=seq, k_steps=k_steps, tracer=tracer,
+        cfg_file=cfg_file, mix=mix, model=model, spec=model.spec(cfg_file),
+        cfg=cfg, points=points, seq=seq, k_steps=k_steps, tracer=tracer,
         devices=devices,
         sweep=program.Sweep(cfg, points, devices, seq=seq, steps=k_steps,
-                            kind=kind, tracer=tracer),
+                            kind=kind, adapter_norms=model.adapter_norms,
+                            tracer=tracer),
     )
     log(f"plan: {st.sweep.jobs()}")
     reseed(st, seed)
@@ -198,14 +236,12 @@ def reseed(st, seed):
     import jax
     import numpy as np
 
-    from bench import program
     from bench.traffic.generator import RowBank
-    from bench.weights import make_weights
 
     st.sweep.executor.drop_templates()
     st.weights = st.base = st.records = None  # the last seed's, freed first
-    st.weights = make_weights(seed, st.spec, st.devices[0])
-    st.base = program.base_tree(st.weights, st.cfg)
+    st.weights = st.model.make_weights(seed, st.spec, st.devices[0])
+    st.base = st.model.base_tree(st.weights, st.cfg)
     st.bank = RowBank(seed, st.points,
                       n_steps=max(st.k_steps, CHECK_STEPS), seq=st.seq,
                       vocab=st.spec["V"], noise=float(st.mix["noise"]))
@@ -265,7 +301,7 @@ def run_cell(cell, cfg_file, mix, *, seed, seconds, trace, devices, kind,
     t_process = time.perf_counter() if t_process is None else t_process
     counter = CompileCounter()
     st = setup(cfg_file, mix, seed=seed, devices=devices, kind=kind,
-               trace=trace)
+               trace=trace, root=root)
     passes, t0, t1 = run_window(st, seconds, trace)
     setup_s, window_s = t0 - t_process, t1 - t0
     memory_peak = max(d.memory_stats().get("peak_bytes_in_use", 0)
@@ -280,8 +316,7 @@ def run_cell(cell, cfg_file, mix, *, seed, seconds, trace, devices, kind,
             window_s=window_s, chips=len(devices), peaks=peaks,
             jobs=st.sweep.jobs(), memory_peak_bytes=memory_peak,
             compiles_in_window=counter.between(t0, t1),
-            flops=load_module(os.path.join(root, "bench", "flops",
-                                           f"{cfg_file['model']}.py")),
+            flops=model_module(cfg_file, "flops", root),
             spans=[s for s in st.tracer.spans() if t0 <= s.start <= t1],
         )
         metrics, breakdown = read_trace(ctx, readers or {}, t0)
@@ -352,9 +387,7 @@ def reference_results(st, *, lowp=False, root=ROOT):
     ``lowp`` trains it in the control's precision."""
     from bench.traffic.generator import point_key
 
-    model = st.cfg_file["model"]
-    ref = load_module(os.path.join(root, "bench", "reference", f"{model}.py"),
-                      "bench_reference_" + model)
+    ref = model_module(st.cfg_file, "reference", root)
     inits = ref.init_adapters(
         st.lora_seed, st.spec, {r["pack_ranks"] for r in st.records.values()},
         max(p["rank"] for p in st.points))
@@ -386,7 +419,8 @@ def as_records(refs):
 
 
 def _leaves(tree):
-    """{proj.a|b: (L,)} of a {proj: {a|b: (L,)}} tree."""
+    """{name.a|b: (layers,)} of a {name: {a|b: (layers,)}} tree; names
+    may count different layers."""
     return {f"{p}.{ab}": v for p, d in tree.items() for ab, v in d.items()}
 
 

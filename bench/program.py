@@ -1,58 +1,24 @@
 """The system under test, as the benchmark drives it.
 
-Everything here calls the program (``src/repro``): its configuration, the
-planner, ``ExecutionEngine.run_local`` with ``ClusterRunner`` and
-``SliceExecutor``. The benchmark gives the program its weights and its rows;
+Everything here calls the program (``src/repro``): the planner,
+``ExecutionEngine.run_local`` with ``ClusterRunner`` and ``SliceExecutor``.
+The benchmark gives the program its configuration, weights and rows; what
+depends on the model's shape comes from its module (``bench/models/``).
 ``RecordingExecutor`` reads what the timed path returns during the check
 passes and adds no work to the window.
 """
 from __future__ import annotations
 
-import dataclasses
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.cluster import ClusterRunner, DevicePool, SliceExecutor
-from repro.configs.base import LoraConfig, get_config
+from repro.configs.base import LoraConfig
 from repro.sched.cost_model import CostModel, tpu_prior
 from repro.sched.engine import ExecutionEngine
 from repro.sched.planner import plan
 
 ADAM_B1 = 0.9
-
-
-def program_config(cfg_file: dict):
-    """The program's ModelConfig with every size taken from the file."""
-    cfg = get_config(cfg_file["arch"])
-    attn = dataclasses.replace(
-        cfg.attention,
-        n_heads=cfg_file["num_attention_heads"],
-        n_kv_heads=cfg_file["num_key_value_heads"],
-        head_dim=cfg_file["head_dim"],
-        rope_theta=float(cfg_file["rope_theta"]),
-    )
-    cfg = cfg.replace(
-        n_layers=cfg_file["num_hidden_layers"],
-        d_model=cfg_file["hidden_size"],
-        d_ff=cfg_file["intermediate_size"],
-        vocab_size=cfg_file["vocab_size"],
-        attention=attn,
-    )
-    want = {"norm_kind": cfg_file["norm"], "mlp_kind": cfg_file["mlp"],
-            "tie_embeddings": False,
-            "lora_targets": tuple(cfg_file["lora_targets"])}
-    # the program puts an adapter on each target its layers have
-    has = ("q", "k", "v", "o") + (("up", "down") if cfg.mlp_kind == "gelu2"
-                                  else ("gate", "up", "down"))
-    got = {"norm_kind": cfg.norm_kind, "mlp_kind": cfg.mlp_kind,
-           "tie_embeddings": cfg.tie_embeddings,
-           "lora_targets": tuple(t for t in cfg.lora_targets if t in has)}
-    if want != got:
-        raise ValueError(f"{cfg_file['name']}: the program's {cfg.name} runs "
-                         f"{got}, the file says {want}")
-    return cfg
 
 
 def lora_configs(points, seq):
@@ -62,64 +28,17 @@ def lora_configs(points, seq):
             for p in points]
 
 
-def base_tree(weights: dict, cfg):
-    """The program's base-parameter tree, filled with the benchmark's
-    weights. A bias the program carries and the architecture lacks is
-    zero."""
-    from repro.models.model import init_model
-
-    shapes = jax.eval_shape(
-        lambda: init_model(jax.random.PRNGKey(0), cfg, None, jnp.bfloat16)[0])
-    blocks = shapes["decoder"]["blocks"]
-    if set(blocks) != {"l0"} or shapes["decoder"]["rest"]:
-        raise ValueError("the benchmark maps stacks of one repeated layer only")
-
-    def name_of(path):
-        keys = [getattr(k, "key", None) for k in path]
-        if keys[:1] == ["embed"]:
-            return "embed"
-        if keys[:1] == ["lm_head"]:
-            return "lm_head"
-        if keys[0] == "final_norm":
-            return f"final_norm_{keys[1]}"
-        return f"{keys[-2]}_{keys[-1]}"  # norm1_scale, q_w, up_b, ...
-
-    def fill(path, sds):
-        name = name_of(path)
-        if name in weights:
-            w = weights[name]
-            if w.shape != sds.shape:  # vocabulary padded to a 256 multiple
-                pad = [(0, t - s) for s, t in zip(w.shape, sds.shape)]
-                w = jnp.pad(w, pad)
-            return w
-        if name.endswith("_b") or name.endswith("_bias"):
-            return jnp.zeros(sds.shape, sds.dtype)
-        raise KeyError(f"no benchmark weight for the program's {name}")
-
-    return jax.tree_util.tree_map_with_path(fill, shapes)
-
-
-def _per_leaf_norms(tree):
-    """{proj: {a|b: (L, n)}} Frobenius norms of each layer's matrices of
-    every adapter slot, from the program's packed adapter tree."""
-    blocks = tree["decoder"]["blocks"]["l0"]
-    out = {}
-    for part in blocks.values():
-        for proj, ab in part.items():
-            out[proj] = {k: jnp.sqrt(jnp.sum(jnp.square(v), (2, 3)))
-                         for k, v in ab.items()}
-    return out
-
-
 class RecordingExecutor(SliceExecutor):
     """A ``SliceExecutor`` that, while ``mode`` is set, keeps what each
     packed run returned: the per-adapter loss of every step, and per-matrix
     norms of the first gradient (``mode="grad"``, read from Adam's first
-    moment) or of the change after the run (``mode="update"``). With
+    moment) or of the change after the run (``mode="update"``), as the
+    model module's ``adapter_norms`` reads them from the adapter tree. With
     ``mode=None`` it is the plain executor."""
 
-    def __init__(self, *, tracer=None):
+    def __init__(self, adapter_norms, *, tracer=None):
         super().__init__(tracer=tracer)
+        self.adapter_norms = adapter_norms
         self.mode = None
         self.records = {}
 
@@ -136,13 +55,13 @@ class RecordingExecutor(SliceExecutor):
             np.asarray(m["per_adapter_loss"]))
         res = super().train_pack(cfg, configs, **kw)
         if self.mode == "grad":
-            norms = _per_leaf_norms(res.opt["m"])
+            norms = self.adapter_norms(res.opt["m"])
             norms = jax.tree.map(lambda x: x / (1 - ADAM_B1), norms)
         else:
             tmpl, _ = self.pack_template(cfg, configs, kw.get("seed", 0))
             delta = jax.tree.map(
                 lambda a, b: a - jax.device_put(b, a.sharding), res.lora, tmpl)
-            norms = _per_leaf_norms(delta)
+            norms = self.adapter_norms(delta)
         norms = jax.tree.map(np.asarray, norms)
         ranks = tuple(c.rank for c in configs)
         for slot, c in enumerate(configs):
@@ -157,16 +76,17 @@ class RecordingExecutor(SliceExecutor):
 
 class Sweep:
     """One planned sweep on ``devices``: the plan, the engine and the
-    runner whose compiled steps and templates every pass reuses."""
+    runner whose compiled steps and templates every pass reuses.
+    ``adapter_norms`` is the model module's."""
 
     def __init__(self, cfg, points, devices, *, seq, steps, kind,
-                 tracer=None):
+                 adapter_norms, tracer=None):
         self.cfg = cfg
         self.seq = seq
         self.configs = lora_configs(points, seq)
         self.cm = CostModel(cfg, tpu_prior(kind))
         self.schedule = plan(self.cm, self.configs, len(devices), seq, steps)
-        self.executor = RecordingExecutor(tracer=tracer)
+        self.executor = RecordingExecutor(adapter_norms, tracer=tracer)
         self.runner = ClusterRunner(self.executor, DevicePool(list(devices)),
                                     tracer=tracer)
         self.engine = ExecutionEngine(self.cm, len(devices), tracer=tracer)

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import lora_projections
+from bench.models.dense_lora import lora_projections
 
 IGNORE = -100
 B1, B2, EPS = 0.9, 0.999, 1e-8

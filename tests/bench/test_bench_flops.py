@@ -9,7 +9,7 @@ sys.path.insert(0, ROOT)
 import pytest  # noqa: E402
 
 from bench.flops import dense_lora as fl  # noqa: E402
-from bench.weights import model_spec  # noqa: E402
+from bench.models.dense_lora import spec as model_spec  # noqa: E402
 
 
 def spec(name):
