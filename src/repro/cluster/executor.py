@@ -378,7 +378,7 @@ class SliceExecutor:
                 "executor.train", cat="executor", track=track,
                 n_pack=meta.n, n_steps=n_steps,
                 rows=rows, real_rows=sum(meta.batch_sizes),
-            ):
+            ) as train_span:
                 t0 = time.perf_counter()
                 i = 0
                 batches = first
@@ -411,7 +411,15 @@ class SliceExecutor:
                 wall = time.perf_counter() - t0
             with self.tracer.span("executor.losses", cat="executor",
                                   track=track):
-                losses = np.asarray(m["per_adapter_loss"])
+                losses, expert_rows, computed = jax.device_get(
+                    (m["per_adapter_loss"], m.get("expert_rows"),
+                     m.get("expert_rows_computed")))
+                losses = np.asarray(losses)
+            if expert_rows is not None and expert_rows.size:
+                # the last step's routed rows, and the rows the grouped
+                # matmuls computed for them
+                train_span.args["routed_rows"] = int(expert_rows.sum())
+                train_span.args["routed_rows_computed"] = int(computed)
         return PackResult(
             lora=lora_d,
             opt=opt_d,

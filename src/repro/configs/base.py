@@ -35,12 +35,22 @@ class AttentionConfig:
     global_rope_theta: float = 0.0
 
     # Multi-head Latent Attention (MiniCPM3 / DeepSeek-V2 style). When
-    # kv_lora_rank > 0 the layer uses MLA projections instead of plain GQA.
+    # kv_lora_rank > 0 the layer uses MLA projections instead of plain GQA;
+    # q_lora_rank 0 then means a direct q projection (d, H * (nope + rope)).
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+
+    # YaRN rope scaling (DeepSeek-V2). factor 0 = plain rope.
+    yarn_factor: float = 0.0
+    yarn_original_max_positions: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    # the softmax scale's mscale; the cos/sin tables are left unscaled, as
+    # they are where mscale equals it (DeepSeek-V2)
+    yarn_mscale_all_dim: float = 0.0
 
     @property
     def is_mla(self) -> bool:
@@ -54,11 +64,18 @@ class MoEConfig:
     d_expert: int = 0  # expert hidden (ffn) size
     # every `moe_every` layers the FFN is MoE (1 = all layers, 2 = alternating)
     moe_every: int = 1
+    # the first `first_k_dense` layers keep a dense FFN (DeepSeek-V2's
+    # first_k_dense_replace); the pattern above starts after them
+    first_k_dense: int = 0
+    # shared experts, run for every token as one SwiGLU of width
+    # n_shared_experts * d_expert and added to the routed output
+    n_shared_experts: int = 0
+    # renormalise the top-k gates to sum to 1
+    norm_topk_prob: bool = True
     # "dense" = all-experts einsum (exact, small-scale / oracle)
-    # "ep"    = expert-parallel shard_map + all_to_all (requires E % tp == 0)
+    # "ep"    = drop-free routed experts (grouped matmul over the routed
+    #           rows); under a mesh each model-axis shard holds E / tp
     impl: str = "dense"
-    capacity_factor: float = 1.25
-    router_jitter: float = 0.0
 
     @property
     def enabled(self) -> bool:
@@ -159,9 +176,9 @@ class ModelConfig:
         for i in range(self.n_layers):
             if self.family == "ssm":
                 kinds.append("none")  # mamba2 blocks have no separate FFN
-            elif self.moe.enabled and (i % self.moe.moe_every) == (
-                self.moe.moe_every - 1
-            ):
+            elif (self.moe.enabled and i >= self.moe.first_k_dense
+                  and (i - self.moe.first_k_dense) % self.moe.moe_every
+                  == self.moe.moe_every - 1):
                 kinds.append("moe")
             else:
                 kinds.append("dense")
@@ -263,16 +280,15 @@ def reduced(cfg: ModelConfig, n_layers: int = 2, d_model: int = 256) -> ModelCon
     )
     moe = cfg.moe
     if moe.enabled:
-        # capacity_factor = E/top_k => capacity >= T: no token dropping, so
-        # step-wise and full-sequence routing agree exactly in smoke tests
         moe = dataclasses.replace(
             moe, n_experts=4, top_k=min(2, moe.top_k), d_expert=64,
-            capacity_factor=4 / min(2, moe.top_k),
         )
     ssm = cfg.ssm
     if ssm.enabled:
         ssm = dataclasses.replace(ssm, d_state=16, head_dim=32, chunk_size=32)
     nl = n_layers
+    if moe.first_k_dense:
+        nl = max(nl, moe.first_k_dense + 2)  # the dense head + 2 MoE layers
     if cfg.family == "hybrid":
         # keep one attn + ssm layers; shrink the attn period instead
         nl = 4
@@ -332,6 +348,7 @@ def _ensure_loaded():
         qwen3_moe_30b_a3b,
         whisper_tiny,
         minicpm3_4b,
+        deepseek_v2_lite,
         gemma3_1b,
         command_r_35b,
         jamba_v01_52b,

@@ -19,7 +19,7 @@ from repro.configs.base import AttentionConfig
 from repro.core.adapter import PackMeta, init_lora_pair
 from repro.core.packed_lora import lora_linear
 from repro.models.layers.common import init_linear
-from repro.models.layers.rope import apply_rope, rope_tables
+from repro.models.layers.rope import apply_rope, rope_tables, yarn_mscale
 
 # ---------------------------------------------------------------------------
 # Core attention math
@@ -261,24 +261,39 @@ def init_mla(key, acfg: AttentionConfig, d_model, meta, targets, dtype=jnp.float
     h = acfg.n_heads
     qlr, kvlr = acfg.q_lora_rank, acfg.kv_lora_rank
     dn, dr, dv = acfg.qk_nope_head_dim, acfg.qk_rope_head_dim, acfg.v_head_dim
-    params = {
-        "q_a": init_linear(ks[0], d_model, qlr, False, dtype),
-        "q_norm": {"scale": jnp.ones((qlr,), dtype)},
-        "q_b": init_linear(ks[1], qlr, h * (dn + dr), False, dtype),
+    if qlr:
+        params = {
+            "q_a": init_linear(ks[0], d_model, qlr, False, dtype),
+            "q_norm": {"scale": jnp.ones((qlr,), dtype)},
+            "q_b": init_linear(ks[1], qlr, h * (dn + dr), False, dtype),
+        }
+    else:  # direct q projection (DeepSeek-V2-Lite)
+        params = {"q": init_linear(ks[0], d_model, h * (dn + dr), False, dtype)}
+    params.update({
         "kv_a": init_linear(ks[2], d_model, kvlr + dr, False, dtype),
         "kv_norm": {"scale": jnp.ones((kvlr,), dtype)},
         "kv_b_k": init_linear(ks[3], kvlr, h * dn, False, dtype),
         "kv_b_v": init_linear(ks[4], kvlr, h * dv, False, dtype),
         "o": init_linear(ks[5], h * dv, d_model, False, dtype),
-    }
+    })
     lora = {}
     if meta is not None:
-        tmap = {"q": "q_a", "kv": "kv_a", "o": "o"}
+        tmap = {"q": "q_a" if qlr else "q", "kv": "kv_a", "o": "o"}
         for i, (t, pname) in enumerate(tmap.items()):
             if t in targets:
                 d_in, d_out = params[pname]["w"].shape
                 lora[pname] = init_lora_pair(ks[6 + i], meta, d_in, d_out, dtype)
     return params, lora
+
+
+def mla_softmax_scale(acfg: AttentionConfig) -> float:
+    """``(nope + rope)^-0.5``, times YaRN's ``mscale(mscale_all_dim)^2``
+    where the rope is YaRN-scaled (DeepSeek-V2)."""
+    scale = (acfg.qk_nope_head_dim + acfg.qk_rope_head_dim) ** -0.5
+    if acfg.yarn_factor and acfg.yarn_mscale_all_dim:
+        m = yarn_mscale(acfg.yarn_factor, acfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
 
 
 def _mla_qkv(params, lo, scales, x, n_pack, acfg, rope, kcfg=None):
@@ -289,9 +304,13 @@ def _mla_qkv(params, lo, scales, x, n_pack, acfg, rope, kcfg=None):
     h = acfg.n_heads
     dn, dr, dv = acfg.qk_nope_head_dim, acfg.qk_rope_head_dim, acfg.v_head_dim
     cos, sin = rope
-    cq = lora_linear(x, params["q_a"], lo.get("q_a"), scales, n_pack, kcfg=kcfg)
-    cq = apply_norm(params["q_norm"], cq, "rmsnorm")
-    q = lora_linear(cq, params["q_b"], None, scales, n_pack, kcfg=kcfg).reshape(nb, s, h, dn + dr)
+    if "q" in params:
+        q = lora_linear(x, params["q"], lo.get("q"), scales, n_pack, kcfg=kcfg)
+    else:
+        cq = lora_linear(x, params["q_a"], lo.get("q_a"), scales, n_pack, kcfg=kcfg)
+        cq = apply_norm(params["q_norm"], cq, "rmsnorm")
+        q = lora_linear(cq, params["q_b"], None, scales, n_pack, kcfg=kcfg)
+    q = q.reshape(nb, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     q_rope = apply_rope(q_rope, cos, sin)
 
@@ -321,16 +340,17 @@ def apply_mla(
     nb, s, _ = x.shape
     h = acfg.n_heads
     dn, dr, dv = acfg.qk_nope_head_dim, acfg.qk_rope_head_dim, acfg.v_head_dim
-    scale = (dn + dr) ** -0.5
+    scale = mla_softmax_scale(acfg)
     q_nope, q_rope, ckv, k_rope = _mla_qkv(params, lo, scales, x, n_pack, acfg, rope, kcfg)
 
     if cache is None:
         # train/prefill: expand compressed KV to per-head K/V
-        k_nope = (ckv @ params["kv_b_k"]["w"].astype(ckv.dtype)).reshape(nb, s, h, dn)
-        v = (ckv @ params["kv_b_v"]["w"].astype(ckv.dtype)).reshape(nb, s, h, dv)
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (nb, s, h, dr))], -1)
-        q = jnp.concatenate([q_nope, q_rope], -1)
-        out = flash_attention(q, k, v, causal=True, chunk_q=chunk_q, scale=scale)
+        with jax.named_scope("mla.attend"):
+            k_nope = (ckv @ params["kv_b_k"]["w"].astype(ckv.dtype)).reshape(nb, s, h, dn)
+            v = (ckv @ params["kv_b_v"]["w"].astype(ckv.dtype)).reshape(nb, s, h, dv)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (nb, s, h, dr))], -1)
+            q = jnp.concatenate([q_nope, q_rope], -1)
+            out = flash_attention(q, k, v, causal=True, chunk_q=chunk_q, scale=scale)
         new_cache = {"ckv": ckv, "k_rope": k_rope[:, :, 0, :]} if make_cache else None
     else:
         # absorbed decode: score against the compressed cache directly.

@@ -1,16 +1,28 @@
-"""Mixture-of-Experts FFN: dense (exact oracle) and expert-parallel paths.
+"""Mixture-of-Experts FFN: routed experts, drop-free, plus shared experts.
+
+Routing: a softmax over the router's logits in float32, greedy top-k,
+renormalised only when ``norm_topk_prob`` is set (DeepSeek-V2's ``MoEGate``
+with ``routed_scaling_factor`` 1). The router is frozen
+and carries no adapter; the layer adds no loss term (a balance term over a
+pack would mix its adapters' gradients).
 
 ``impl="dense"`` computes every expert for every token and combines with the
-top-k gate weights — exact, used for smoke tests / small E, and for Grok-1
-whose E=8 does not divide the mandated 16-way model axis (weights are then
-FSDP-sharded over data x model; see DESIGN.md §4).
+top-k gates — exact, used for small E (Grok-1's E=8) and as the test oracle.
 
-``impl="ep"`` is the production path: activations are replicated across the
-model axis (they already are, under megatron-style TP), experts are sharded
-over it, and each shard routes the full local token set to its own experts
-with a capacity-bounded sort-based dispatch (no giant one-hot). Partial
-outputs are combined with a single psum over the model axis — the same
-collective cost as a TP MLP. Requires E % model_axis == 0.
+``impl="ep"`` is the routed path, and drops nothing: the (token, expert)
+pairs are sorted by expert and the three expert products run as one grouped
+matmul each (``kernels.moe_gmm``) over exactly ``T * top_k`` rows, however
+skewed the routing; the backward pass makes the input gradients only (the
+experts are frozen). The layer is told which experts it holds
+(``e_lo``, ``e_local``): all of them on one chip, its share of the model
+axis inside the expert-parallel ``shard_map``, where every shard routes all
+local tokens, computes its own experts' part and the parts are summed by a
+psum over the axis. So one packed adapter's rows can never push another's
+out of an expert.
+
+Shared experts (``n_shared_experts``) are one SwiGLU of width
+``n_shared * d_expert`` run for every token, with LoRA on gate/up/down
+through the same ``lora_linear`` as the dense MLP.
 """
 from __future__ import annotations
 
@@ -20,51 +32,55 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
-from repro.models.layers.common import init_linear
+from repro.kernels.moe_gmm import moe_gmm
+from repro.models.layers.common import apply_mlp, init_linear, init_mlp
 
 
-def init_moe(key, d_model: int, mcfg: MoEConfig, dtype=jnp.float32):
-    ks = jax.random.split(key, 4)
+def init_moe(key, d_model: int, mcfg: MoEConfig, meta=None, targets=(),
+             dtype=jnp.float32):
+    """(params, lora) of one MoE layer; LoRA only on the shared experts."""
+    ks = jax.random.split(key, 5)
     e, f = mcfg.n_experts, mcfg.d_expert
     s = d_model**-0.5
-    return {
+    params = {
         "router": init_linear(ks[0], d_model, e, False, jnp.float32),
         "w_gate": jax.random.normal(ks[1], (e, d_model, f), dtype) * s,
         "w_up": jax.random.normal(ks[2], (e, d_model, f), dtype) * s,
         "w_down": jax.random.normal(ks[3], (e, f, d_model), dtype) * (f**-0.5),
     }
+    lora = {}
+    if mcfg.n_shared_experts:
+        p, lo = init_mlp(ks[4], d_model, mcfg.n_shared_experts * f, "swiglu",
+                         False, meta, targets, dtype)
+        params["shared"] = p
+        if lo:
+            lora["shared"] = lo
+    return params, lora
 
 
 def _router(x, params, mcfg: MoEConfig):
-    """x: (T, d) -> (gates (T, k) normalized, idx (T, k), aux load-balance loss)."""
-    logits = (x.astype(jnp.float32)) @ params["router"]["w"]
+    """x: (T, d) -> (gates (T, k) float32, idx (T, k))."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params["router"]["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, mcfg.top_k)
-    gates = gates / (gates.sum(-1, keepdims=True) + 1e-9)
-    # Switch-style load-balance aux loss: E * sum_e f_e * p_e
-    e = mcfg.n_experts
-    me = probs.mean(0)
-    ce = jnp.zeros((e,), jnp.float32).at[idx.reshape(-1)].add(1.0) / (
-        idx.size + 1e-9
-    )
-    aux = e * jnp.sum(me * ce)
-    return gates, idx, aux
+    if mcfg.norm_topk_prob:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return gates, idx
 
 
-def _expert_ffn(w_gate, w_up, w_down, h):
-    """h: (E_loc, C, d) -> (E_loc, C, d), SwiGLU experts via batched GEMM."""
-    g = jnp.einsum("ecd,edf->ecf", h, w_gate.astype(h.dtype))
-    u = jnp.einsum("ecd,edf->ecf", h, w_up.astype(h.dtype))
-    return jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u, w_down.astype(h.dtype))
+def expert_rows(idx, n_experts: int):
+    """(E,) int32: the routed rows each expert receives."""
+    return jnp.zeros((n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
 
 
-def _moe_dense(params, x, mcfg: MoEConfig, chunk: int = 1024):
+def _moe_dense(params, x, gates, idx, n_experts: int, chunk: int = 1024):
     """All-experts compute, gate-weighted combine; token-chunked so the
     (chunk, E, f) intermediate stays small. FLOPs are E/top_k x the routed
     cost — acceptable for small E (Grok-1 E=8) and exact for testing."""
     t, d = x.shape
-    gates, idx, aux = _router(x, params, mcfg)
-    comb = jnp.zeros((t, mcfg.n_experts), x.dtype)
+    comb = jnp.zeros((t, n_experts), x.dtype)
     comb = comb.at[jnp.arange(t)[:, None], idx].set(gates.astype(x.dtype))
 
     def one_chunk(args):
@@ -78,60 +94,63 @@ def _moe_dense(params, x, mcfg: MoEConfig, chunk: int = 1024):
         )
 
     if t <= chunk:
-        y = one_chunk((x, comb))
-    else:
-        n = t // chunk
-        pad = n * chunk < t
-        if pad:
-            n += 1
-            xpad = jnp.pad(x, ((0, n * chunk - t), (0, 0)))
-            cpad = jnp.pad(comb, ((0, n * chunk - t), (0, 0)))
-        else:
-            xpad, cpad = x, comb
-        y = jax.lax.map(
-            jax.checkpoint(one_chunk),
-            (xpad.reshape(n, chunk, d), cpad.reshape(n, chunk, -1)),
-        ).reshape(n * chunk, d)[:t]
-    return y, aux
+        return one_chunk((x, comb))
+    n = -(-t // chunk)
+    xpad = jnp.pad(x, ((0, n * chunk - t), (0, 0)))
+    cpad = jnp.pad(comb, ((0, n * chunk - t), (0, 0)))
+    return jax.lax.map(
+        jax.checkpoint(one_chunk),
+        (xpad.reshape(n, chunk, d), cpad.reshape(n, chunk, -1)),
+    ).reshape(n * chunk, d)[:t]
 
 
-def _moe_ep_local(params_local, x, mcfg: MoEConfig, e_lo, e_local: int, capacity: int):
-    """Process the local expert slice [e_lo, e_lo + e_local) for all local
-    tokens; returns this shard's partial output (psum'd by the caller)."""
+@jax.custom_vjp
+def _permute(x, idx, inv):
+    """``x[idx]`` for a permutation ``idx`` whose inverse is ``inv``; the
+    backward pass is the gather ``g[inv]``, not a scatter."""
+    return jnp.take(x, idx, axis=0)
+
+
+def _permute_fwd(x, idx, inv):
+    return _permute(x, idx, inv), (idx, inv)
+
+
+def _permute_bwd(res, g):
+    idx, inv = res
+    return jnp.take(g, inv, axis=0), None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def _moe_routed(params, x, gates, idx, e_lo, e_local: int, n_experts: int):
+    """The part of the layer's routed output that experts
+    [e_lo, e_lo + e_local) give, drop-free: every (token, expert) pair of a
+    held expert is one row of the grouped matmuls."""
     t, d = x.shape
-    gates, idx, aux = _router(x, params_local, mcfg)
-    k = mcfg.top_k
-    flat_e = idx.reshape(-1)  # (T*k,)
-    flat_g = gates.reshape(-1)
-    tok_of = jnp.repeat(jnp.arange(t), k)
-
-    order = jnp.argsort(flat_e, stable=True)
-    se = flat_e[order]
-    counts = jnp.zeros((mcfg.n_experts,), jnp.int32).at[flat_e].add(1)
-    starts = jnp.cumsum(counts) - counts
-    pos_in_e = jnp.arange(t * k) - starts[se]
-
-    local = (se >= e_lo) & (se < e_lo + e_local) & (pos_in_e < capacity)
-    slot = jnp.where(local, (se - e_lo) * capacity + pos_in_e, e_local * capacity)
-    buf = jnp.zeros((e_local * capacity + 1, d), x.dtype).at[slot].set(x[tok_of[order]])
-    h = buf[:-1].reshape(e_local, capacity, d)
-    y_e = _expert_ffn(
-        params_local["w_gate"], params_local["w_up"], params_local["w_down"], h
-    ).reshape(e_local * capacity, d)
-    y_e = jnp.concatenate([y_e, jnp.zeros((1, d), y_e.dtype)], 0)
-    contrib = y_e[slot] * (flat_g[order] * local.astype(jnp.float32)).astype(
-        y_e.dtype
-    )[:, None]
-    y = jnp.zeros((t, d), x.dtype).at[tok_of[order]].add(contrib)
-    return y, aux
+    k = idx.shape[1]
+    e = idx.reshape(-1) - e_lo
+    held = (e >= 0) & (e < e_local)
+    with jax.named_scope("moe.dispatch"):
+        # rows of held experts first, in expert order; the rest after them
+        key = jnp.where(held, e, e_local)
+        order = jnp.argsort(key, stable=True)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        sizes = jnp.zeros((e_local + 1,), jnp.int32).at[key].add(1)[:e_local]
+        xs = _permute(jnp.repeat(x, k, axis=0), order, inv)
+    share = e_local < n_experts  # rows of other shards' experts: zeroed
+    with jax.named_scope("moe.experts"):
+        g = moe_gmm(xs, params["w_gate"], sizes, share)
+        u = moe_gmm(xs, params["w_up"], sizes, share)
+        ys = moe_gmm(jax.nn.silu(g) * u, params["w_down"], sizes, share)
+    with jax.named_scope("moe.combine"):
+        y = _permute(ys, inv, order).reshape(t, k, d)
+        w = jnp.where(held.reshape(t, k), gates, 0.0)
+        return jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w).astype(x.dtype)
 
 
-def moe_capacity(t: int, mcfg: MoEConfig) -> int:
-    c = int(t * mcfg.top_k / mcfg.n_experts * mcfg.capacity_factor) + 1
-    return max(8, min(c, t))
-
-
-def apply_moe(
+def routed_experts(
     params,
     x: jnp.ndarray,
     mcfg: MoEConfig,
@@ -139,26 +158,31 @@ def apply_moe(
     model_axis: Optional[str] = None,
     model_axis_size: int = 1,
 ):
-    """x: (NB, S, d) -> (y, aux_loss).
+    """The routed experts of one layer. x: (NB, S, d) -> (y, rows), rows the
+    (E,) int32 routed rows of each expert.
 
-    When called inside shard_map with ``model_axis`` set, params hold only the
-    local expert slice and the partial outputs are psum'd over the axis.
-    Outside shard_map (CPU tests), all experts are local.
+    Inside shard_map with ``model_axis`` set (``impl="ep"``), params hold only
+    this shard's experts and the partial outputs are psum'd over the axis.
     """
     nb, s, d = x.shape
     xt = x.reshape(nb * s, d)
+    with jax.named_scope("moe.route"):
+        gates, idx = _router(xt, params, mcfg)
+        rows = expert_rows(idx, mcfg.n_experts)
     if mcfg.impl == "dense":
-        y, aux = _moe_dense(params, xt, mcfg)
-        if model_axis is not None:
-            aux = jax.lax.pmean(aux, model_axis)
+        y = _moe_dense(params, xt, gates, idx, mcfg.n_experts)
+    elif model_axis is not None:
+        e_local = mcfg.n_experts // model_axis_size
+        e_lo = jax.lax.axis_index(model_axis) * e_local
+        y = _moe_routed(params, xt, gates, idx, e_lo, e_local, mcfg.n_experts)
+        y = jax.lax.psum(y, model_axis)
     else:
-        e_local = mcfg.n_experts // max(model_axis_size, 1)
-        cap = moe_capacity(nb * s, mcfg)
-        if model_axis is not None:
-            e_lo = jax.lax.axis_index(model_axis) * e_local
-            y, aux = _moe_ep_local(params, xt, mcfg, e_lo, e_local, cap)
-            y = jax.lax.psum(y, model_axis)
-            aux = jax.lax.pmean(aux, model_axis)
-        else:
-            y, aux = _moe_ep_local(params, xt, mcfg, 0, mcfg.n_experts, cap)
-    return y.reshape(nb, s, d), aux
+        y = _moe_routed(params, xt, gates, idx, 0, mcfg.n_experts,
+                        mcfg.n_experts)
+    return y.reshape(nb, s, d), rows
+
+
+def apply_shared(params, lora, scales, x, n_pack: int = 1, kcfg=None):
+    """The shared experts' output for every token (x: (NB, S, d))."""
+    return apply_mlp(params["shared"], (lora or {}).get("shared"), scales, x,
+                     "swiglu", n_pack, kcfg=kcfg)

@@ -4,10 +4,10 @@ Public API (all functional, params are plain pytrees):
 
   init_model(key, cfg, meta, dtype)          -> (base_params, lora_params)
   init_lora(key, cfg, meta, dtype)           -> lora_params (no base built)
-  forward(base, lora, scales, batch, cfg, .) -> (hidden (NB,S,d), aux)
+  forward(base, lora, scales, batch, cfg, .) -> (hidden (NB,S,d), caches, expert_rows)
   logits(base, hidden, cfg)                  -> (NB,S,V)   [small seqs only]
   init_caches(cfg, nb, smax)                 -> cache pytree
-  prefill(...)                               -> (hidden, caches, aux)
+  prefill(...)                               -> (last logits, caches)
   decode_step(...)                           -> (logits (NB,1,V), caches)
 
 The pack dim N is folded into the leading batch: every tensor is (N*B, ...).
@@ -109,7 +109,8 @@ def forward(
     kcfg=None,
 ):
     """batch: {"tokens": (NB, S)[, "frames": (NB,Se,d)][, "patches": (NB,P,d)]}.
-    Returns (hidden (NB, S_total, d), caches|None, aux). ``kcfg`` is the
+    Returns (hidden (NB, S_total, d), caches|None, expert_rows), the last
+    the (MoE layers, E) int32 routed rows of each expert. ``kcfg`` is the
     static kernel policy (impl / remat / pack rank vector) every
     ``lora_linear`` below runs under."""
     tokens = batch["tokens"]
@@ -124,14 +125,14 @@ def forward(
     positions = jnp.arange(s_total)
     rc = make_rope_cache(cfg, positions)
     specs = layer_specs(cfg)
-    x, caches, aux = apply_stack(
+    x, caches, rows = apply_stack(
         base["decoder"], lora.get("decoder", {"blocks": {}, "rest": {}}),
         scales, x, cfg, specs,
         n_pack=n_pack, rope_cache=rc, dist=dist, enc_out=enc_out,
         make_cache=make_cache, chunk_q=chunk_q, kcfg=kcfg,
     )
     x = apply_norm(base["final_norm"], x, cfg.norm_kind)
-    return x, (caches if make_cache else None), aux
+    return x, (caches if make_cache else None), rows
 
 
 def unembed_w(base, cfg: ModelConfig):

@@ -28,7 +28,7 @@ from repro.models.layers.attention import (
     init_mla_cache,
 )
 from repro.models.layers.common import apply_mlp, apply_norm, init_linear, init_mlp, init_norm
-from repro.models.layers.moe import apply_moe, init_moe
+from repro.models.layers.moe import apply_shared, init_moe, routed_experts
 from repro.models.layers.rope import rope_tables
 from repro.models.layers.ssm import (
     apply_ssm,
@@ -161,8 +161,12 @@ def init_layer(key, cfg: ModelConfig, spec: LayerSpec, meta, dtype):
         if lo:
             lora["mlp"] = lo
     elif spec.ffn == "moe":
-        params["moe"] = init_moe(ks[2], cfg.d_model, cfg.moe, dtype)
+        p, lo = init_moe(ks[2], cfg.d_model, cfg.moe, meta, cfg.lora_targets,
+                         dtype)
+        params["moe"] = p
         params["norm2"] = init_norm(cfg.d_model, cfg.norm_kind, dtype)
+        if lo:
+            lora["moe"] = lo
     return params, lora
 
 
@@ -189,9 +193,10 @@ def apply_layer(
     causal: bool = True,
     kcfg=None,
 ):
-    """Pre-norm residual layer. Returns (x, new_cache, aux_loss)."""
+    """Pre-norm residual layer. Returns (x, new_cache, rows): rows is the
+    (E,) int32 routed rows of each expert for an MoE layer, else None."""
     lo = lora or {}
-    aux = jnp.zeros((), jnp.float32)
+    rows = None
     new_cache: Dict[str, Any] = {}
     h = apply_norm(params["norm1"], x, cfg.norm_kind)
     if spec.mixer == "ssm":
@@ -264,6 +269,8 @@ def apply_layer(
         x = x + apply_mlp(params["mlp"], lo.get("mlp"), scales, h, cfg.mlp_kind, n_pack, kcfg=kcfg)
     elif spec.ffn == "moe":
         h = apply_norm(params["norm2"], x, cfg.norm_kind)
+        mp = params["moe"]
+        routed = {k: mp[k] for k in ("router", "w_gate", "w_up", "w_down")}
         if dist is not None and dist.model_axis is not None and cfg.moe.impl == "ep":
             from jax.sharding import PartitionSpec as P
 
@@ -276,28 +283,31 @@ def apply_layer(
                 "w_down": P(dist.model_axis, None, None),
             }
 
-            def _moe_body(mp, hh):
-                y, aux_local = apply_moe(
-                    mp, hh, cfg.moe,
+            def _moe_body(mp_local, hh):
+                y, rows = routed_experts(
+                    mp_local, hh, cfg.moe,
                     model_axis=dist.model_axis,
                     model_axis_size=dist.model_axis_size,
                 )
+                # the model-axis shards route the same tokens alike; the
+                # data shards' rows add up
                 if da:
-                    aux_local = jax.lax.pmean(aux_local, da)
-                return y, aux_local
+                    rows = jax.lax.psum(rows, da)
+                return y, rows
 
-            y, aux_l = jax.shard_map(
+            y, rows = jax.shard_map(
                 _moe_body,
                 mesh=dist.mesh,
                 in_specs=(moe_specs, x_spec),
                 out_specs=(x_spec, P()),
                 check_vma=False,
-            )(params["moe"], h)
+            )(routed, h)
         else:
-            y, aux_l = apply_moe(params["moe"], h, cfg.moe)
-        aux = aux + aux_l
+            y, rows = routed_experts(routed, h, cfg.moe)
+        if "shared" in mp:
+            y = y + apply_shared(mp, lo.get("moe"), scales, h, n_pack, kcfg)
         x = x + y
-    return x, (new_cache or None), aux
+    return x, (new_cache or None), rows
 
 
 # ---------------------------------------------------------------------------
@@ -309,17 +319,36 @@ def _stack(trees: List[Any]):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
 
+def head_length(cfg: ModelConfig, specs: List[LayerSpec]) -> int:
+    """Leading layers run one by one ahead of the scanned period: an MoE
+    stack's first ``first_k_dense`` dense layers (DeepSeek-V2)."""
+    if any(s.ffn == "moe" for s in specs):
+        return cfg.moe.first_k_dense
+    return 0
+
+
 def init_stack(key, cfg: ModelConfig, specs: List[LayerSpec], meta, dtype):
-    """Returns ({"blocks": stacked, "rest": dict}, same-for-lora, period)."""
-    p = find_period(specs)
-    L = len(specs)
+    """Returns ({["head": dict,] "blocks": stacked, "rest": dict},
+    same-for-lora, period). The period is that of the layers after the
+    head."""
+    n_head = head_length(cfg, specs)
+    body = specs[n_head:]
+    p = find_period(body)
+    L = len(body)
     n_blocks, n_rest = L // p, L % p
-    keys = jax.random.split(key, L)
+    keys = jax.random.split(key, len(specs))
+    head_p, head_l = {}, {}
+    for i in range(n_head):
+        lp, ll = init_layer(keys[i], cfg, specs[i], meta, dtype)
+        head_p[f"l{i}"] = lp
+        if ll:
+            head_l[f"l{i}"] = ll
+    keys = keys[n_head:]
     blocks_p, blocks_l = [], []
     for b in range(n_blocks):
         bp, bl = {}, {}
         for i in range(p):
-            lp, ll = init_layer(keys[b * p + i], cfg, specs[i], meta, dtype)
+            lp, ll = init_layer(keys[b * p + i], cfg, body[i], meta, dtype)
             bp[f"l{i}"] = lp
             if ll:
                 bl[f"l{i}"] = ll
@@ -327,12 +356,14 @@ def init_stack(key, cfg: ModelConfig, specs: List[LayerSpec], meta, dtype):
         blocks_l.append(bl)
     rest_p, rest_l = {}, {}
     for i in range(n_rest):
-        lp, ll = init_layer(keys[n_blocks * p + i], cfg, specs[i], meta, dtype)
+        lp, ll = init_layer(keys[n_blocks * p + i], cfg, body[i], meta, dtype)
         rest_p[f"l{i}"] = lp
         if ll:
             rest_l[f"l{i}"] = ll
     params = {"blocks": _stack(blocks_p) if n_blocks else {}, "rest": rest_p}
     lora = {"blocks": _stack(blocks_l) if (n_blocks and blocks_l[0]) else {}, "rest": rest_l}
+    if n_head:
+        params["head"], lora["head"] = head_p, head_l
     return params, lora, p
 
 
@@ -356,65 +387,86 @@ def apply_stack(
     remat: bool = True,
     kcfg=None,
 ):
-    """Run the whole stack. Returns (x, new_caches, total_aux)."""
-    p = find_period(specs)
-    L = len(specs)
+    """Run the whole stack. Returns (x, new_caches, expert_rows):
+    expert_rows is (MoE layers, E) int32, the routed rows of each expert of
+    each MoE layer in layer order ((0, E) without MoE layers)."""
+    n_head = head_length(cfg, specs)
+    body_specs = specs[n_head:]
+    p = find_period(body_specs)
+    L = len(body_specs)
     n_blocks, n_rest = L // p, L % p
     kw = dict(
         cfg=cfg, n_pack=n_pack, rope_cache=rope_cache, dist=dist,
         chunk_q=chunk_q, causal=causal, kcfg=kcfg,
     )
+    rows = []
 
     def block_body(x, inp):
         bp, bl, bc = inp
         new_c = {}
-        aux = jnp.zeros((), jnp.float32)
+        rs = []
         if dist is not None:
             x = dist.residual_constraint(x)
         for i in range(p):
-            x, c, a = apply_layer(
-                bp[f"l{i}"], (bl or {}).get(f"l{i}"), scales, x, specs[i],
+            x, c, r = apply_layer(
+                bp[f"l{i}"], (bl or {}).get(f"l{i}"), scales, x, body_specs[i],
                 enc_out=enc_out,
                 cache=(bc or {}).get(f"l{i}") if bc is not None else None,
                 pos=pos, make_cache=make_cache, **kw,
             )
             if c is not None:
                 new_c[f"l{i}"] = c
-            aux = aux + a
-        return x, (new_c if (make_cache or caches is not None) else None, aux)
+            if r is not None:
+                rs.append(r)
+        return x, (new_c if (make_cache or caches is not None) else None,
+                   jnp.stack(rs) if rs else None)
 
-    body = jax.checkpoint(block_body) if remat else block_body
-    total_aux = jnp.zeros((), jnp.float32)
     new_caches = {"blocks": None, "rest": {}}
+    if n_head:
+        new_caches["head"] = {}
+    for i in range(n_head):
+        x, c, r = apply_layer(
+            params["head"][f"l{i}"], (lora.get("head") or {}).get(f"l{i}"),
+            scales, x, specs[i], enc_out=enc_out,
+            cache=(caches["head"].get(f"l{i}") if caches is not None else None),
+            pos=pos, make_cache=make_cache, **kw,
+        )
+        if c is not None:
+            new_caches["head"][f"l{i}"] = c
+        if r is not None:
+            rows.append(r[None])
+    body = jax.checkpoint(block_body) if remat else block_body
     if n_blocks:
-        bc = caches["blocks"] if caches is not None else None
-        xs_in = (params["blocks"], lora.get("blocks") or None, bc)
         if caches is None:
-            xs_in = (params["blocks"], lora.get("blocks") or None, None)
-            x, (cs, auxs) = jax.lax.scan(
+            x, (cs, rs) = jax.lax.scan(
                 lambda xx, inp: body(xx, (inp[0], inp[1], None)),
                 x,
                 (params["blocks"], _none_like(lora.get("blocks"))),
             )
         else:
-            x, (cs, auxs) = jax.lax.scan(
+            x, (cs, rs) = jax.lax.scan(
                 lambda xx, inp: body(xx, inp),
                 x,
-                (params["blocks"], _none_like(lora.get("blocks")), bc),
+                (params["blocks"], _none_like(lora.get("blocks")),
+                 caches["blocks"]),
             )
         new_caches["blocks"] = cs
-        total_aux = total_aux + auxs.sum()
+        if rs is not None:
+            rows.append(rs.reshape(-1, rs.shape[-1]))
     for i in range(n_rest):
-        x, c, a = apply_layer(
+        x, c, r = apply_layer(
             params["rest"][f"l{i}"], (lora.get("rest") or {}).get(f"l{i}"),
-            scales, x, specs[i], enc_out=enc_out,
+            scales, x, body_specs[i], enc_out=enc_out,
             cache=(caches["rest"].get(f"l{i}") if caches is not None else None),
             pos=pos, make_cache=make_cache, **kw,
         )
         if c is not None:
             new_caches["rest"][f"l{i}"] = c
-        total_aux = total_aux + a
-    return x, new_caches, total_aux
+        if r is not None:
+            rows.append(r[None])
+    rows = (jnp.concatenate(rows) if rows
+            else jnp.zeros((0, cfg.moe.n_experts), jnp.int32))
+    return x, new_caches, rows
 
 
 def _none_like(tree):
@@ -430,12 +482,18 @@ def make_rope_cache(cfg: ModelConfig, positions: jnp.ndarray):
     thetas = {s.theta for s in layer_specs(cfg) if s.mixer == "attn"}
     if not thetas:
         thetas = {a.rope_theta}
-    return {t: rope_tables(positions, dim, t) for t in thetas}
+    yarn = None
+    if a.yarn_factor:
+        yarn = (a.yarn_factor, a.yarn_original_max_positions,
+                a.yarn_beta_fast, a.yarn_beta_slow)
+    return {t: rope_tables(positions, dim, t, yarn) for t in thetas}
 
 
 def init_stack_cache(cfg, specs, nb: int, smax: int, dtype=jnp.bfloat16):
     """Cache pytree matching apply_stack(caches=...) structure."""
     a = cfg.attention
+    n_head = head_length(cfg, specs)
+    head, specs = specs[:n_head], specs[n_head:]
     p = find_period(specs)
     L = len(specs)
     n_blocks, n_rest = L // p, L % p
@@ -461,7 +519,10 @@ def init_stack_cache(cfg, specs, nb: int, smax: int, dtype=jnp.bfloat16):
     blocks = [
         {f"l{i}": one(specs[i]) for i in range(p)} for _ in range(n_blocks)
     ]
-    return {
+    out = {
         "blocks": _stack(blocks) if n_blocks else None,
         "rest": {f"l{i}": one(specs[i]) for i in range(n_rest)},
     }
+    if n_head:
+        out["head"] = {f"l{i}": one(head[i]) for i in range(n_head)}
+    return out

@@ -204,6 +204,21 @@ def tpu_prior(device_kind: str) -> HardwareSpec:
         ) from None
 
 
+def _mla_shapes(a, d: int) -> Dict[str, Tuple[int, int]]:
+    """(d_in, d_out) of each MLA projection: q through a low-rank pair
+    (q_a, q_b) or, with q_lora_rank 0, direct."""
+    qk = a.qk_nope_head_dim + a.qk_rope_head_dim
+    shapes = {"kv_a": (d, a.kv_lora_rank + a.qk_rope_head_dim),
+              "kv_b": (a.kv_lora_rank, a.n_heads * (a.qk_nope_head_dim + a.v_head_dim)),
+              "o": (a.n_heads * a.v_head_dim, d)}
+    if a.q_lora_rank:
+        shapes["q_a"] = (d, a.q_lora_rank)
+        shapes["q_b"] = (a.q_lora_rank, a.n_heads * qk)
+    else:
+        shapes["q"] = (d, a.n_heads * qk)
+    return shapes
+
+
 def model_param_count(cfg: ModelConfig) -> float:
     """Total parameters (embeddings + stack), honest per-family accounting."""
     a = cfg.attention
@@ -212,11 +227,7 @@ def model_param_count(cfg: ModelConfig) -> float:
     for mixer, ffn in zip(cfg.layer_kinds(), cfg.ffn_kinds()):
         if mixer == "attn":
             if a.is_mla:
-                qk = a.qk_nope_head_dim + a.qk_rope_head_dim
-                total += d * a.q_lora_rank + a.q_lora_rank * a.n_heads * qk
-                total += d * (a.kv_lora_rank + a.qk_rope_head_dim)
-                total += a.kv_lora_rank * a.n_heads * (a.qk_nope_head_dim + a.v_head_dim)
-                total += a.n_heads * a.v_head_dim * d
+                total += sum(i * o for i, o in _mla_shapes(a, d).values())
             else:
                 hd = a.head_dim
                 total += d * hd * (a.n_heads + 2 * a.n_kv_heads) + a.n_heads * hd * d
@@ -228,7 +239,9 @@ def model_param_count(cfg: ModelConfig) -> float:
             mats = 2 if cfg.mlp_kind == "gelu2" else 3
             total += mats * d * cfg.d_ff
         elif ffn == "moe":
-            total += cfg.moe.n_experts * 3 * d * cfg.moe.d_expert + d * cfg.moe.n_experts
+            m = cfg.moe
+            total += m.n_experts * 3 * d * m.d_expert + d * m.n_experts
+            total += 3 * d * m.n_shared_experts * m.d_expert
     if cfg.is_encdec:
         mats = 2 if cfg.mlp_kind == "gelu2" else 3
         per_enc = d * a.head_dim * (a.n_heads + 2 * a.n_kv_heads) + a.n_heads * a.head_dim * d
@@ -240,7 +253,8 @@ def model_param_count(cfg: ModelConfig) -> float:
 
 
 def active_param_count(cfg: ModelConfig) -> float:
-    """Parameters touched per token (MoE: top_k of E experts)."""
+    """Parameters touched per token (MoE: top_k of E routed experts, and
+    every shared expert)."""
     total = model_param_count(cfg)
     if cfg.moe.enabled:
         moe_layers = sum(1 for f in cfg.ffn_kinds() if f == "moe")
@@ -250,29 +264,39 @@ def active_param_count(cfg: ModelConfig) -> float:
 
 
 def lora_param_count(cfg: ModelConfig, rank: int) -> float:
-    """Packed-LoRA params for one adapter over cfg.lora_targets."""
+    """Packed-LoRA params for one adapter over cfg.lora_targets, layer by
+    layer: an MoE layer's gate/up/down adapters sit on its shared experts
+    (none without them; routed experts carry no adapter)."""
     a, d = cfg.attention, cfg.d_model
-    shapes = {
+    attn = {
         "q": (d, a.n_heads * a.head_dim),
         "k": (d, a.n_kv_heads * a.head_dim),
         "v": (d, a.n_kv_heads * a.head_dim),
         "o": (a.n_heads * a.head_dim, d),
-        "gate": (d, cfg.d_ff),
-        "up": (d, cfg.d_ff),
-        "down": (cfg.d_ff, d),
         "kv": (d, a.kv_lora_rank + a.qk_rope_head_dim),
         "ssm_in": (d, 2 * cfg.ssm.d_inner(d)),
         "ssm_out": (cfg.ssm.d_inner(d), d),
     }
     if a.is_mla:
-        shapes["q"] = (d, a.q_lora_rank)
-    per_layer = 0.0
-    for t in cfg.lora_targets:
-        if t in shapes:
-            din, dout = shapes[t]
-            per_layer += rank * (din + dout)
-    n_layers = cfg.n_layers + cfg.encoder_layers
-    return float(per_layer * n_layers)
+        mla = _mla_shapes(a, d)
+        attn["q"] = mla["q_a"] if a.q_lora_rank else mla["q"]
+        attn["o"] = mla["o"]
+
+    def mlp(width):
+        return {"gate": (d, width), "up": (d, width), "down": (width, d)}
+
+    def layer(ffn):
+        shapes = dict(attn)
+        if ffn == "dense":
+            shapes.update(mlp(cfg.d_ff))
+        elif ffn == "moe" and cfg.moe.n_shared_experts:
+            shapes.update(mlp(cfg.moe.n_shared_experts * cfg.moe.d_expert))
+        return sum(rank * (i + o) for t, (i, o) in shapes.items()
+                   if t in cfg.lora_targets)
+
+    total = sum(layer(f) for f in cfg.ffn_kinds())
+    total += cfg.encoder_layers * layer("dense")
+    return float(total)
 
 
 @dataclass

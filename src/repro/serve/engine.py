@@ -45,7 +45,8 @@ Three pieces:
 Bit-exactness: decode rows are computed independently (batched einsums), so
 a row served in a width-``rows`` continuous batch emits exactly the tokens
 the same request emits under width-1 sequential decode — for dense models.
-MoE capacity couples rows; serve bit-exactness claims use non-MoE configs.
+Serve bit-exactness claims use non-MoE configs: an MoE layer's grouped
+expert matmuls tile each row together with other rows of its expert.
 """
 from __future__ import annotations
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.adapter import PackMeta
+from repro.kernels.moe_gmm import grid_rows
 from repro.kernels.ops import KernelConfig, sharded_impl
 from repro.models.model import forward, unembed_w
 from repro.models.transformer import DistContext
@@ -110,7 +111,6 @@ def packed_loss_fn(
     dist: Optional[DistContext] = None,
     chunk_q: int = 512,
     vocab_chunk: int = 512,
-    aux_weight: float = 0.01,
     kcfg: Optional[KernelConfig] = None,
     batch_sizes: Optional[tuple] = None,
 ):
@@ -120,7 +120,12 @@ def packed_loss_fn(
     impl, backward remat, the pack's rank vector for ragged grouping).
     ``batch_sizes``, the pack's static batch-size tuple, runs a mixed-batch
     pack's real rows as slots (``row_slots``); None keeps the padded
-    layout."""
+    layout.
+
+    The loss is the sum of the adapters' LM losses and nothing else: a term
+    over the whole pack, such as an MoE load-balance loss, would mix the
+    packed adapters' gradients. Returns (total, (per_adapter, expert_rows)),
+    the last the (MoE layers, E) routed rows of each expert."""
     owner = row_slots(batch_sizes, dist)
     n_rows = n_pack
     if owner is not None:
@@ -129,7 +134,7 @@ def packed_loss_fn(
             jnp.dtype(base["embed"]["w"].dtype),
         )
         n_rows = len(owner)
-    h, _, aux = forward(
+    h, _, rows = forward(
         base, lora, scales, batch, cfg,
         n_pack=n_rows, dist=dist, chunk_q=chunk_q, kcfg=kcfg,
     )
@@ -137,7 +142,7 @@ def packed_loss_fn(
         h, unembed_w(base, cfg), batch["labels"], n_pack,
         chunk=vocab_chunk, vocab=cfg.vocab_size, row_owner=owner,
     )
-    return total + aux_weight * aux, per_adapter
+    return total, (per_adapter, rows)
 
 
 def loss_fn(
@@ -150,13 +155,11 @@ def loss_fn(
     dist: Optional[DistContext] = None,
     chunk_q: int = 512,
     vocab_chunk: int = 512,
-    aux_weight: float = 0.01,
     kcfg: Optional[KernelConfig] = None,
 ):
     return packed_loss_fn(
         lora, base, batch, cfg, meta.n, meta.scales(),
         dist=dist, chunk_q=chunk_q, vocab_chunk=vocab_chunk,
-        aux_weight=aux_weight,
         kcfg=kcfg if kcfg is not None else meta.kernel_config(),
         batch_sizes=meta.batch_sizes,
     )
@@ -214,7 +217,7 @@ def make_packed_step(
     )
 
     def train_step(base, lora, opt_state, batch, scales, lr_vec, budgets):
-        (total, per_adapter), grads = jax.value_and_grad(
+        (total, (per_adapter, rows)), grads = jax.value_and_grad(
             packed_loss_fn, has_aux=True
         )(lora, base, batch, cfg, n_pack, scales,
           dist=dist, chunk_q=chunk_q, vocab_chunk=vocab_chunk, kcfg=kcfg,
@@ -223,7 +226,9 @@ def make_packed_step(
             grads, opt_state, lora, lr_vec, weight_decay=weight_decay,
             step_budget=budgets,
         )
-        metrics = {"loss": total, "per_adapter_loss": per_adapter}
+        metrics = {"loss": total, "per_adapter_loss": per_adapter,
+                   "expert_rows": rows,
+                   "expert_rows_computed": grid_rows(rows)}
         return lora_new, opt_state, metrics
 
     return jax.jit(train_step, donate_argnums=(1, 2)) if jit else train_step
@@ -252,7 +257,7 @@ def make_train_step(
     kcfg = meta.kernel_config(impl=impl, remat=remat, base_dtype=base_dtype)
 
     def train_step(base, lora, opt_state, batch):
-        (total, per_adapter), grads = jax.value_and_grad(
+        (total, (per_adapter, rows)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(lora, base, batch, cfg, meta,
           dist=dist, chunk_q=chunk_q, vocab_chunk=vocab_chunk, kcfg=kcfg)
@@ -260,7 +265,9 @@ def make_train_step(
             grads, opt_state, lora, lr_vec, weight_decay=weight_decay,
             step_budget=budgets,
         )
-        metrics = {"loss": total, "per_adapter_loss": per_adapter}
+        metrics = {"loss": total, "per_adapter_loss": per_adapter,
+                   "expert_rows": rows,
+                   "expert_rows_computed": grid_rows(rows)}
         return lora_new, opt_state, metrics
 
     return jax.jit(train_step, donate_argnums=(1, 2)) if jit else train_step

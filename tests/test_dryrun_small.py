@@ -224,38 +224,85 @@ def test_flash_decode_cache_layout_same_values():
 
 
 def test_moe_ep_shard_map_on_mesh():
-    """Expert-parallel MoE under shard_map on a real (1 data x 4 model) mesh
-    == the dense oracle (capacity at no-drop)."""
+    """Expert-parallel MoE under shard_map on a real (2 data x 4 model) mesh
+    == the dense oracle, drop-free: under a router that sends every token
+    to the first model shard's experts (the skew a capacity-bounded
+    dispatch dropped rows under) nothing is lost, and the row counts are the
+    whole layer's."""
     r = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.configs.base import MoEConfig
-        from repro.models.layers.moe import apply_moe, init_moe
+        from repro.models.layers.moe import init_moe, routed_experts
         from repro.launch.mesh import make_host_mesh
 
         mesh = make_host_mesh(2, 4)
-        mcfg = MoEConfig(n_experts=4, top_k=2, d_expert=8, impl="ep",
-                         capacity_factor=2.0)
-        params = init_moe(jax.random.PRNGKey(0), 16, mcfg)
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))
-        y_ref, aux_ref = apply_moe(params, x, MoEConfig(
-            n_experts=4, top_k=2, d_expert=8, impl="dense", capacity_factor=2.0))
+        mcfg = MoEConfig(n_experts=8, top_k=2, d_expert=8, impl="ep")
+        params, _ = init_moe(jax.random.PRNGKey(0), 16, mcfg)
+        params["router"]["w"] = (jnp.zeros_like(params["router"]["w"])
+                                 .at[:, 0].set(2.0).at[:, 1].set(1.0))
+        x = jnp.abs(jax.random.normal(jax.random.PRNGKey(1), (4, 8, 16))) + 0.1
+        y_ref, rows_ref = routed_experts(params, x, MoEConfig(
+            n_experts=8, top_k=2, d_expert=8, impl="dense"))
+        assert rows_ref.tolist() == [32, 32, 0, 0, 0, 0, 0, 0], rows_ref
 
         def body(p, xx):
-            return apply_moe(p, xx, mcfg, model_axis="model", model_axis_size=4)
+            y, rows = routed_experts(p, xx, mcfg, model_axis="model",
+                                     model_axis_size=4)
+            return y, jax.lax.psum(rows, "data")
 
         specs = {"router": {"w": P()}, "w_gate": P("model", None, None),
                  "w_up": P("model", None, None), "w_down": P("model", None, None)}
         with mesh:
-            y, aux = jax.jit(jax.shard_map(
+            y, rows = jax.jit(jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(specs, P("data", None, None)),
                 out_specs=(P("data", None, None), P()),
                 check_vma=False,
             ))(params, x)
-        # capacity C=T*k/E*cf = 8*... per-shard T = 16 tokens, cap >= demand
+        # every routed row of the hot shard lands: the drop-free answer
         np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                    rtol=5e-3, atol=5e-3)
+        assert np.asarray(rows).tolist() == rows_ref.tolist()
+        print("OK")
+    """)
+    assert r.returncode == 0, r.stderr[-3000:]
+
+
+def test_deepseek_ep_mesh_matches_single_device():
+    """DeepSeek-V2 (reduced, 8 experts top-3, a dense head layer, shared
+    experts with LoRA) on a (2 data x 4 model) mesh: each model shard holds
+    2 experts and computes their part drop-free; the pack's loss and the
+    routed rows of each expert equal the single-device step's."""
+    r = _run("""
+        import dataclasses
+        import jax, numpy as np
+        from repro.configs.base import LoraConfig, get_config, reduced
+        from repro.core.adapter import pack_meta
+        from repro.launch.mesh import make_host_mesh
+        from repro.launch.sharding import make_dist, param_specs, to_named
+        from repro.models.model import init_model
+        from repro.train.data import packed_batch_iterator
+        from repro.train.trainer import loss_fn
+
+        cfg = reduced(get_config("deepseek-v2-lite"), d_model=256)
+        cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, n_experts=8, top_k=3))
+        configs = [LoraConfig(rank=8, alpha=8., learning_rate=1e-3, batch_size=2)
+                   for _ in range(2)]
+        meta = pack_meta(configs)
+        base, lora = init_model(jax.random.PRNGKey(0), cfg, meta)
+        b = next(packed_batch_iterator(cfg, configs, seq=16))
+        l0, (_, rows0) = loss_fn(lora, base, b, cfg, meta)
+        mesh = make_host_mesh(2, 4)
+        with mesh:
+            dist = make_dist(mesh, meta.n * meta.max_batch)
+            spec = lambda t: to_named(param_specs(jax.eval_shape(lambda: t), cfg, mesh), mesh)
+            base_d = jax.device_put(base, spec(base))
+            lora_d = jax.device_put(lora, spec(lora))
+            l1, (_, rows1) = jax.jit(
+                lambda lo: loss_fn(lo, base_d, b, cfg, meta, dist=dist))(lora_d)
+        np.testing.assert_allclose(float(l0), float(l1), rtol=1e-4)
+        assert np.array_equal(np.asarray(rows0), np.asarray(rows1))
         print("OK")
     """)
     assert r.returncode == 0, r.stderr[-3000:]

@@ -48,11 +48,14 @@ def test_forward_shapes_and_finite(arch, key, meta2):
     base, lora = M.init_model(key, cfg, meta2)
     nb = meta2.n * meta2.max_batch
     batch = _batch_for(cfg, key, nb, SEQ)
-    h, _, aux = M.forward(base, lora, meta2.scales(), batch, cfg, n_pack=meta2.n)
+    h, _, rows = M.forward(base, lora, meta2.scales(), batch, cfg, n_pack=meta2.n)
     s_total = SEQ + (cfg.n_patch_tokens or 0)
     assert h.shape == (nb, s_total, cfg.d_model)
     assert bool(jnp.isfinite(h).all())
-    assert bool(jnp.isfinite(aux))
+    # every MoE layer routes each token to top_k experts, none dropped
+    n_moe = cfg.ffn_kinds().count("moe")
+    assert rows.shape == (n_moe, cfg.moe.n_experts)
+    assert (np.asarray(rows).sum(-1) == nb * s_total * cfg.moe.top_k).all()
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -89,6 +92,7 @@ def test_full_config_dims_match_assignment(arch):
         "qwen3-moe-30b-a3b": dict(n_layers=48, d_model=2048, vocab_size=151_936),
         "whisper-tiny": dict(n_layers=4, d_model=384, vocab_size=51_865),
         "minicpm3-4b": dict(n_layers=62, d_model=2560, vocab_size=73_448),
+        "deepseek-v2-lite": dict(n_layers=27, d_model=2048, vocab_size=102_400),
         "gemma3-1b": dict(n_layers=26, d_model=1152, vocab_size=262_144),
         "command-r-35b": dict(n_layers=40, d_model=8192, vocab_size=256_000),
         "jamba-v0.1-52b": dict(n_layers=32, d_model=4096, vocab_size=65_536),

@@ -134,3 +134,90 @@ def test_packed_matmul_backward_cases_compile(one_chip, case):
         s(xs), s(ws), s((N,), jnp.float32),
     )
     assert "tpu_custom_call" in text
+
+
+# DeepSeek-V2-Lite's routed experts: 64 experts of width 1408 over d 2048,
+# top-6 rows of a (1, 2) pack at seq 1024
+E_MOE, D_MOE, F_MOE, ROWS_MOE = 64, 2048, 1408, 3 * 1024 * 6
+
+
+@pytest.mark.parametrize("zero_rest", [False, True])
+def test_moe_gmm_compiles(one_chip, zero_rest):
+    """The grouped matmul's three expert products forward and their input
+    gradients (the same kernel against transposed weights); the kernel is
+    named ``moe_gmm`` in the program, as the device trace names it."""
+    from repro.kernels import moe_gmm as mg
+
+    s = lambda shape, dt=jnp.bfloat16: _shape(one_chip, shape, dt)  # noqa: E731
+
+    def experts(x, wg, wu, wd, sizes):
+        def fwd(x):
+            g = mg._gmm(x, wg, sizes, zero_rest=zero_rest, interpret=False)
+            u = mg._gmm(x, wu, sizes, zero_rest=zero_rest, interpret=False)
+            return mg._gmm(jax.nn.silu(g) * u, wd, sizes,
+                           zero_rest=zero_rest, interpret=False)
+        y = fwd(x)
+        dh = mg._gmm(y, wd, sizes, transpose_rhs=True, zero_rest=zero_rest,
+                     interpret=False)
+        return y, mg._gmm(dh, wg, sizes, transpose_rhs=True,
+                          zero_rest=zero_rest, interpret=False)
+
+    text = _compile(
+        experts, s((ROWS_MOE, D_MOE)), s((E_MOE, D_MOE, F_MOE)),
+        s((E_MOE, D_MOE, F_MOE)), s((E_MOE, F_MOE, D_MOE)),
+        s((E_MOE,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text and "%moe_gmm" in text
+
+
+def test_deepseek_grid_plan_fits_one_chip(one_chip, monkeypatch):
+    """The planner's plan for the ten-point grid (ranks 8-128 x batch 1-2 at
+    seq 1024) on DeepSeek-V2-Lite cut to 5 layers puts each pair on one
+    chip, and the largest pack's step program, compiled for the chip with
+    its Pallas kernels, fits in its memory with the frozen base."""
+    from repro.configs.base import LoraConfig, get_config
+    from repro.core.adapter import pack_meta
+    from repro.kernels import fused, moe_gmm, ops
+    from repro.models.model import init_lora, init_model
+    from repro.sched.cost_model import CostModel, tpu_prior
+    from repro.sched.planner import plan
+    from repro.train.optimizer import init_opt_state
+    from repro.train.trainer import make_packed_step
+
+    for mod in (fused, moe_gmm, ops):  # compile the kernels, do not interpret
+        monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
+    cfg = get_config("deepseek-v2-lite").replace(n_layers=5)
+    configs = [LoraConfig(rank=r, alpha=a * r, learning_rate=lr, batch_size=b,
+                          seq_len=1024)
+               for b, lr, a in ((1, 1e-4, 1.0), (2, 4e-4, 0.25))
+               for r in (8, 16, 32, 64, 128)]
+    sched = plan(CostModel(cfg, tpu_prior("TPU v5 lite")), configs, 1, 1024, 4)
+    jobs = [[configs[i] for i in j.config_ids] for j in sched.jobs]
+    assert all(j.degree == 1 for j in sched.jobs)
+    assert sorted(len(j) for j in jobs) == [2] * 5
+    pack = max(jobs, key=lambda j: max(c.rank for c in j))
+    meta = pack_meta(pack)
+    put = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: _shape(one_chip, x.shape, x.dtype), t)
+    lora = jax.eval_shape(lambda: init_lora(jax.random.PRNGKey(0), cfg, meta))
+    base = jax.eval_shape(
+        lambda: init_model(jax.random.PRNGKey(0), cfg, None, jnp.bfloat16)[0])
+    opt = jax.eval_shape(lambda: init_opt_state(lora, n_pack=meta.n))
+    nb = meta.n * meta.max_batch
+    batch = {k: jax.ShapeDtypeStruct((nb, 1024), jnp.int32)
+             for k in ("tokens", "labels")}
+    vecs = (jax.ShapeDtypeStruct((meta.n,), jnp.float32),) * 2 + (
+        jax.ShapeDtypeStruct((meta.n,), jnp.int32),)
+    step = make_packed_step(cfg, meta.n, impl="pallas",
+                            batch_sizes=meta.batch_sizes)
+    compiled = step.lower(*put((base, lora, opt, batch) + vecs)).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert used < 15.75e9, used / 1e9
+    text = compiled.as_text()
+    assert "%moe_gmm" in text
+    # the named scopes the device trace's operations carry in their metadata
+    for scope in ("moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+                  "mla.attend"):
+        assert f"/{scope}/" in text, scope
